@@ -21,6 +21,7 @@ Standalone:  PYTHONPATH=src python -m benchmarks.bench_serving --quick
 
 from __future__ import annotations
 
+import functools
 import time
 
 import numpy as np
@@ -155,9 +156,8 @@ def run(ctx: BenchCtx) -> list[dict]:
             for rank in ranks:
                 op = AxOOperator.from_config(cfgv, rank=rank)
                 dep = deploy_axo(params, op, cfg, impl=impl, ctx=ectx)
-                pre_a = jax.jit(make_prefill_step(cfg, rules, max_seq=max_seq,
-                                                  axo=dep))
-                dec_a = jax.jit(make_decode_step(cfg, rules, axo=dep))
+                pre_a = functools.partial(prefill, axo=dep)
+                dec_a = functools.partial(decode, axo=dep)
                 _gen(pre_a, dec_a, params, toks, gen)       # warm
                 t0 = time.perf_counter()
                 axo_toks, _ = _gen(pre_a, dec_a, params, toks, gen)

@@ -96,6 +96,9 @@ def main(argv=None) -> int:
                          "experiments/bench_history/")
     args = ap.parse_args(argv)
     repeats = max(1, args.repeats)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     # provenance captured once per run, stamped into every suite entry (the
     # regression sentinel refuses to reason about rows with no origin)

@@ -14,6 +14,7 @@ actually lives -- not on random synthetic hidden states.
 """
 
 import argparse
+import functools
 import time
 
 import jax
@@ -78,9 +79,10 @@ def pick_operator(seed: int = 0, behav_cap: float = 1.0) -> np.ndarray:
 
 def build_steps(cfg, rules, max_seq, axo=None):
     """jit'd (prefill, decode) step pair, optionally AxO-deployed."""
-    prefill = jax.jit(make_prefill_step(cfg, rules, max_seq=max_seq, axo=axo))
-    decode = jax.jit(make_decode_step(cfg, rules, axo=axo))
-    return prefill, decode
+    prefill = jax.jit(make_prefill_step(cfg, rules, max_seq=max_seq))
+    decode = jax.jit(make_decode_step(cfg, rules))
+    return (functools.partial(prefill, axo=axo),
+            functools.partial(decode, axo=axo))
 
 
 def generate(prefill, decode, params, toks, gen: int):

@@ -35,6 +35,7 @@ from repro.core.dse import (
 from repro.core.engine import KERNEL_IMPLS, SHARD_AXES, ExecutionContext
 from repro.core.moo import hypervolume_2d
 from repro.core.operator_model import spec_for
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -76,6 +77,7 @@ def main():
                     help="write a Chrome-trace JSON of the DSE spans to PATH "
                          "(load at ui.perfetto.dev); implies --telemetry on")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.kernel_impl == "list":
         from repro.kernels import registry

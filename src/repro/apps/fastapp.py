@@ -448,6 +448,13 @@ def _resolve_impl(impl: str | None, batch: TableBatch, k: int) -> str:
     impl = default_matmul_impl() if impl is None else impl
     if impl not in MATMUL_IMPLS:
         raise ValueError(f"unknown fastapp impl {impl!r}")
+    if impl == "pallas" and not batch.has_small:
+        if explicit:
+            raise ValueError(
+                "impl='pallas' unavailable: TableBatch built from raw tables "
+                "has no per-row tables"
+            )
+        impl = "xla"
     if impl == "gemm" and not (batch.has_small and _gemm_ok(k, batch.n_bits)):
         if explicit:  # never silently hand back a different impl than asked for
             raise ValueError(
@@ -653,9 +660,8 @@ def table_matmul_jax(
         if pad:  # zero codes index table[0, 0] == 0: padding adds nothing
             a = jnp.concatenate([a, jnp.zeros((a.shape[0], pad), jnp.int32)], axis=1)
             b = jnp.concatenate([b, jnp.zeros((pad, b.shape[1]), jnp.int32)], axis=0)
-        return table_gemv_pallas(
-            batch.tables.reshape(d, -1), a, b, k_tile=k_tile, interpret=interpret
-        )
+        return table_gemv_pallas(batch.small, a, b, k_tile=k_tile,
+                                 interpret=interpret)
 
     if a.ndim == 2 and impl == "entry_pallas":
         from ..kernels.app_kernels import entry_gemv_pallas

@@ -15,8 +15,9 @@ serving path:
      :class:`AxODeployment` -- per-layer **cached** weight codes/scales and
      pre-gathered ``G_r(W)`` factors for every attention q/k/v/o, MLP and MoE
      expert projection (plus the LM head), so decode steps never requantize or
-     re-gather weights per token.  The deployment threads through
-     ``models.model.forward(axo=...)`` and the ``launch.steps`` builders.
+     re-gather weights per token.  The deployment is a pytree and threads
+     through ``models.model.forward(axo=...)`` and the ``launch.steps`` steps
+     as a jit argument.
 
 The bit-exact table path (exhaustive gather) stays available for validation;
 production uses the rank-R MXU path (DESIGN.md §3.2).
@@ -24,6 +25,7 @@ production uses the rank-R MXU path (DESIGN.md §3.2).
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 
 import jax
@@ -53,9 +55,13 @@ __all__ = [
 ]
 
 
-@dataclass(frozen=True)
+@dataclass(frozen=True, eq=False)
 class AxOOperator:
-    """A deployable approximate multiplier: rank-R factorized error tables."""
+    """A deployable approximate multiplier: rank-R factorized error tables.
+
+    Compared and hashed by identity: it is static metadata of the
+    :class:`AxODeployment` pytree, and its tables are numpy arrays.
+    """
 
     n_bits: int
     rank: int
@@ -157,6 +163,11 @@ def axo_linear(
 AXO_LAYERS = ("attn", "mlp", "moe", "head")
 
 
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["f_table", "signed_vals", "stages", "encoder", "head"],
+    meta_fields=["op", "impl", "layers", "ctx", "n_entries"],
+)
 @dataclass(frozen=True)
 class AxODeployment:
     """DSE-selected operator deployed into every linear layer of a model.
@@ -167,6 +178,11 @@ class AxODeployment:
     steps only quantize the (tiny) activation and gather its left factors.
     Entries for stacked layers carry a leading ``repeats`` axis so they ride
     through ``jax.lax.scan`` next to the params.
+
+    A pytree whose leaves are the tables and entries: pass it to a jitted
+    step as an argument.  Closed over, it would be embedded in the program
+    as constants -- 3.4 GB for granite-3-2b's attention projections at
+    rank 1, enough for lowering to exhaust a 40 GiB host.
 
     ``stages[str(si)][str(li)]`` mirrors ``params["stages"]`` with per-layer
     ``{"mixer": ..., "mlp": ...}`` entry dicts; ``encoder`` mirrors the

@@ -97,7 +97,9 @@ def _mesh_for(n_devices: int):
             "XLA_FLAGS=--xla_force_host_platform_device_count=8 before JAX "
             "first initializes"
         )
-    return jax.make_mesh((n_devices,), (MESH_AXIS,), devices=devices[:n_devices])
+    return jax.make_mesh((n_devices,), (MESH_AXIS,),
+                         axis_types=(jax.sharding.AxisType.Auto,),
+                         devices=devices[:n_devices])
 
 
 @dataclass(frozen=True)
@@ -243,10 +245,11 @@ class ExecutionContext:
         return jax.devices()[: self.device_count]
 
     def shard_call(self, fn, in_specs, out_specs):
-        """``shard_map`` of ``fn`` over this context's mesh (portable wrapper)."""
-        from ..models.sharding import shard_map
+        """``jax.shard_map`` of ``fn`` over this context's mesh."""
+        import jax
 
-        return shard_map(fn, self.mesh(), in_specs, out_specs)
+        return jax.shard_map(fn, mesh=self.mesh(), in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def prng_key(self, seed: int):
         """A JAX PRNG key under this context's PRNG policy.

@@ -63,8 +63,11 @@ def _kernel(a_ref, b_ref, fa_ref, gb_ref, o_ref, acc_ref, *, n_k: int, rank: int
     b = b_ref[...]
     acc = jnp.dot(a, b, preferred_element_type=jnp.float32)
     for r in range(rank):                       # static unroll: R extra matmuls
+        # the factors are arbitrary f32 (the values above are small integers,
+        # exact in any MXU pass), so the rank terms need full f32 precision
         acc = acc + jnp.dot(
-            fa_ref[r], gb_ref[r], preferred_element_type=jnp.float32
+            fa_ref[r], gb_ref[r], preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
     acc_ref[...] += acc
 
@@ -132,7 +135,7 @@ def axo_matmul_pallas(
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         cost_estimate=pl.CostEstimate(**cost),
-        compiler_params=pltpu.TPUCompilerParams(**params),
+        compiler_params=params,
         interpret=interpret,
     )(a_vals, b_vals, fa, gb)
     return out if (mp, np_) == (m, n) else out[:m, :n]

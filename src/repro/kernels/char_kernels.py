@@ -63,44 +63,83 @@ __all__ = ["behav_stats_pallas", "behav_stats_entry_pallas", "N_CHAN"]
 N_CHAN = 8  # output channel count (padded for lane alignment)
 
 
-def _kernel(small_ref, exact_ref, w_ref, int_ref, rel_ref, *, rows: int, a_tile: int):
-    """One (d_block, a_tile) step: rebuild the error tile, reduce to partials."""
+def _channel_rows(err, w):
+    """Reduce one config's (Ta, B) error tile to its (1, N_CHAN) partial rows.
+
+    Every reduction keeps rank 2 ((Ta, B) -> (1, 1)) and the channels are
+    placed with lane selects, not a stack of rank-1 values: Mosaic lays out
+    only rank >= 2 values here.
+    """
+    abs_e = jnp.abs(err)
+    hi = abs_e >> 8
+    lo = abs_e & 255
+
+    def total(x):
+        return x.sum(axis=1, keepdims=True).sum(axis=0, keepdims=True)
+
+    chans = (
+        total(abs_e),
+        total((err != 0).astype(jnp.int32)),
+        abs_e.max(axis=1, keepdims=True).max(axis=0, keepdims=True),
+        total(hi * hi),
+        total(hi * lo),
+        total(lo * lo),
+    )
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, N_CHAN), 1)
+    int_row = jnp.zeros((1, N_CHAN), jnp.int32)
+    for c, v in enumerate(chans):
+        int_row = jnp.where(lane == c, v, int_row)
+    rel = total(abs_e.astype(jnp.float32) * w)
+    rel_row = jnp.where(lane == 0, rel, jnp.zeros((1, N_CHAN), jnp.float32))
+    return int_row, rel_row
+
+
+def _per_config(d_block: int, int_ref, rel_ref, rows_fn):
+    """Run ``rows_fn(dd) -> (int_row, rel_row)`` over the block's configs and
+    store the stacked (1, Db, N_CHAN) partials once."""
+    sub = jax.lax.broadcasted_iota(jnp.int32, (d_block, N_CHAN), 0)
+
+    def body(dd, carry):
+        acc_i, acc_f = carry
+        int_row, rel_row = rows_fn(dd)
+        return (jnp.where(sub == dd, int_row, acc_i),
+                jnp.where(sub == dd, rel_row, acc_f))
+
+    acc_i, acc_f = jax.lax.fori_loop(
+        0, d_block, body,
+        (jnp.zeros((d_block, N_CHAN), jnp.int32),
+         jnp.zeros((d_block, N_CHAN), jnp.float32)),
+    )
+    int_ref[...] = acc_i[None]
+    rel_ref[...] = acc_f[None]
+
+
+def _kernel(small_ref, exact_ref, w_ref, int_ref, rel_ref, *, rows: int,
+            a_tile: int, d_block: int):
+    """One (d_block, a_tile) step: rebuild each config's error tile, reduce."""
     j = pl.program_id(1)
     b = exact_ref.shape[-1]
 
     # Absolute A codes covered by this tile, broadcast over the B axis.
     a_ids = jax.lax.broadcasted_iota(jnp.int32, (a_tile, b), 0) + j * a_tile
+    pairs = [2 * ((a_ids >> (2 * r)) & 1) + ((a_ids >> (2 * r + 1)) & 1)
+             for r in range(rows)]
+    exact = exact_ref[...]
+    w = w_ref[...]
 
-    approx = None
-    for r in range(rows):  # static unroll over partial-product rows
-        pair = 2 * ((a_ids >> (2 * r)) & 1) + ((a_ids >> (2 * r + 1)) & 1)
-        acc = None
-        for p in range(4):  # select one of 4 bit-pair planes, no gathers
-            plane = small_ref[r, :, p, :]  # (Db, B)
-            term = jnp.where((pair == p)[None, :, :], plane[:, None, :], 0)
-            acc = term if acc is None else acc + term
-        shifted = acc << (2 * r)
-        approx = shifted if approx is None else approx + shifted
+    def rows_fn(dd):
+        approx = None
+        for r in range(rows):  # static unroll over partial-product rows
+            acc = None
+            for p in range(4):  # select one of 4 bit-pair planes, no gathers
+                plane = small_ref[r, dd, pl.ds(p, 1), :]          # (1, B)
+                term = jnp.where(pairs[r] == p, plane, 0)
+                acc = term if acc is None else acc + term
+            shifted = acc << (2 * r)
+            approx = shifted if approx is None else approx + shifted
+        return _channel_rows(approx - exact, w)                   # (Ta, B) err
 
-    err = approx - exact_ref[...][None]            # (Db, Ta, B) int32
-    abs_e = jnp.abs(err)
-
-    hi = abs_e >> 8
-    lo = abs_e & 255
-    s_abs = abs_e.sum(axis=(1, 2))
-    cnt = (err != 0).astype(jnp.int32).sum(axis=(1, 2))
-    mx = abs_e.max(axis=(1, 2))
-    h2 = (hi * hi).sum(axis=(1, 2))
-    hl = (hi * lo).sum(axis=(1, 2))
-    l2 = (lo * lo).sum(axis=(1, 2))
-    zero = jnp.zeros_like(s_abs)
-    int_ref[...] = jnp.stack(
-        [s_abs, cnt, mx, h2, hl, l2, zero, zero], axis=-1
-    )[None]
-
-    rel = (abs_e.astype(jnp.float32) * w_ref[...][None]).sum(axis=(1, 2))
-    zf = jnp.zeros_like(rel)
-    rel_ref[...] = jnp.stack([rel, zf, zf, zf, zf, zf, zf, zf], axis=-1)[None]
+    _per_config(d_block, int_ref, rel_ref, rows_fn)
 
 
 @functools.partial(jax.jit, static_argnames=("d_block", "a_tile", "interpret"))
@@ -134,7 +173,7 @@ def behav_stats_pallas(
     params = spec.compiler_params(rows=rows, d_block=d_block, a_tile=a_tile, b=b)
     grid = (d // d_block, n_ta)
     return pl.pallas_call(
-        functools.partial(_kernel, rows=rows, a_tile=a_tile),
+        functools.partial(_kernel, rows=rows, a_tile=a_tile, d_block=d_block),
         grid=grid,
         in_specs=[
             pl.BlockSpec((rows, d_block, 4, b), lambda i, j: (0, i, 0, 0)),
@@ -150,7 +189,7 @@ def behav_stats_pallas(
             jax.ShapeDtypeStruct((n_ta, d, N_CHAN), jnp.float32),
         ],
         cost_estimate=pl.CostEstimate(**cost),
-        compiler_params=pltpu.TPUCompilerParams(**params),
+        compiler_params=params,
         interpret=interpret,
     )(small, exact, w)
 
@@ -160,13 +199,15 @@ def behav_stats_pallas(
 # ---------------------------------------------------------------------------
 
 
-def _entry_kernel(masks_ref, int_ref, rel_ref, *, n_bits: int, a_tile: int):
+def _entry_kernel(masks_ref, int_ref, rel_ref, *, n_bits: int, a_tile: int,
+                  d_block: int):
     """One (d_block, a_tile) step with NO table inputs: the per-row planes are
     synthesized in VMEM from the config masks by the carry-chain model
     (``R * 4 * W`` chain steps over the B axis), the exact products and
     relative-error weights from an iota.  The only HBM traffic besides the
-    outputs is the (d_block, R) masks block -- ~4096x less than the
-    ``small``+``exact``+``w`` inputs of the table kernel."""
+    outputs is the (d_block, R) masks block, read as scalars from SMEM --
+    ~4096x less than the ``small``+``exact``+``w`` inputs of the table
+    kernel."""
     spec = spec_for(n_bits)
     j = pl.program_id(1)
     b = spec.n_inputs
@@ -182,44 +223,29 @@ def _entry_kernel(masks_ref, int_ref, rel_ref, *, n_bits: int, a_tile: int):
     a_sv = jnp.where(a_ids >= half, a_ids - b, a_ids)
     b_sv = jnp.where(b_ids >= half, b_ids - b, b_ids)
     exact = a_sv * b_sv                                       # (Ta, B) int32
-
-    approx = None
-    for r in range(spec.rows):  # static unroll over partial-product rows
-        top = r == spec.rows - 1
-        mask_r = masks_ref[:, r][:, None]                     # (Db, 1)
-        bx = -b_s if top else b_s
-        pair = 2 * ((a_ids >> (2 * r)) & 1) + ((a_ids >> (2 * r + 1)) & 1)
-        acc = None
-        for p in range(4):  # synthesize the bit-pair plane, then select it
-            a0, a1 = (p >> 1) & 1, p & 1
-            t1 = (b_s & modw) if a0 else jnp.zeros_like(b_s)
-            t2 = ((bx << 1) & modw) if a1 else jnp.zeros_like(b_s)
-            plane = _chain_eval(t1, t2, mask_r, w_bits, cpr, jnp, jnp.int32)
-            term = jnp.where((pair == p)[None, :, :], plane[:, None, :], 0)
-            acc = term if acc is None else acc + term
-        shifted = acc << (2 * r)
-        approx = shifted if approx is None else approx + shifted
-
-    err = approx - exact[None]                                # (Db, Ta, B) int32
-    abs_e = jnp.abs(err)
-
-    hi = abs_e >> 8
-    lo = abs_e & 255
-    s_abs = abs_e.sum(axis=(1, 2))
-    cnt = (err != 0).astype(jnp.int32).sum(axis=(1, 2))
-    mx = abs_e.max(axis=(1, 2))
-    h2 = (hi * hi).sum(axis=(1, 2))
-    hl = (hi * lo).sum(axis=(1, 2))
-    l2 = (lo * lo).sum(axis=(1, 2))
-    zero = jnp.zeros_like(s_abs)
-    int_ref[...] = jnp.stack(
-        [s_abs, cnt, mx, h2, hl, l2, zero, zero], axis=-1
-    )[None]
-
     w = 1.0 / jnp.maximum(jnp.abs(exact), 1).astype(jnp.float32)
-    rel = (abs_e.astype(jnp.float32) * w[None]).sum(axis=(1, 2))
-    zf = jnp.zeros_like(rel)
-    rel_ref[...] = jnp.stack([rel, zf, zf, zf, zf, zf, zf, zf], axis=-1)[None]
+    pairs = [2 * ((a_ids >> (2 * r)) & 1) + ((a_ids >> (2 * r + 1)) & 1)
+             for r in range(spec.rows)]
+
+    def rows_fn(dd):
+        approx = None
+        for r in range(spec.rows):  # static unroll over partial-product rows
+            top = r == spec.rows - 1
+            mask_r = masks_ref[dd, r]                         # SMEM scalar
+            bx = -b_s if top else b_s
+            acc = None
+            for p in range(4):  # synthesize the bit-pair plane, then select it
+                a0, a1 = (p >> 1) & 1, p & 1
+                t1 = (b_s & modw) if a0 else jnp.zeros_like(b_s)
+                t2 = ((bx << 1) & modw) if a1 else jnp.zeros_like(b_s)
+                plane = _chain_eval(t1, t2, mask_r, w_bits, cpr, jnp, jnp.int32)
+                term = jnp.where(pairs[r] == p, plane, 0)     # (Ta, B)
+                acc = term if acc is None else acc + term
+            shifted = acc << (2 * r)
+            approx = shifted if approx is None else approx + shifted
+        return _channel_rows(approx - exact, w)
+
+    _per_config(d_block, int_ref, rel_ref, rows_fn)
 
 
 @functools.partial(jax.jit, static_argnames=("n_bits", "d_block", "a_tile", "interpret"))
@@ -255,10 +281,12 @@ def behav_stats_entry_pallas(
     params = spec.compiler_params(rows=rows, d_block=d_block, a_tile=a_tile, b=b)
     grid = (d // d_block, n_ta)
     return pl.pallas_call(
-        functools.partial(_entry_kernel, n_bits=n_bits, a_tile=a_tile),
+        functools.partial(_entry_kernel, n_bits=n_bits, a_tile=a_tile,
+                          d_block=d_block),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((d_block, rows), lambda i, j: (i, 0)),
+            pl.BlockSpec((d_block, rows), lambda i, j: (i, 0),
+                         memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, d_block, N_CHAN), lambda i, j: (j, i, 0)),
@@ -269,6 +297,6 @@ def behav_stats_entry_pallas(
             jax.ShapeDtypeStruct((n_ta, d, N_CHAN), jnp.float32),
         ],
         cost_estimate=pl.CostEstimate(**cost),
-        compiler_params=pltpu.TPUCompilerParams(**params),
+        compiler_params=params,
         interpret=interpret,
     )(masks)

@@ -146,7 +146,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
         cost_estimate=pl.CostEstimate(**cost),
-        compiler_params=pltpu.TPUCompilerParams(**params),
+        compiler_params=params,
         interpret=interpret,
     )(q, k, v)
     return out if sqp == sq else out[:, :, :sq]

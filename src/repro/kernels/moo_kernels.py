@@ -20,8 +20,9 @@ iff
 Inputs are passed twice (row tile and column tile of the same arrays), like a
 self-attention kernel:
 
-  objs: (P, n_obj) f32,  viol: (P, 1) f32,  active: (P, 1) i32 mask -- only
-  active *dominators* are counted (every row of the output is computed).
+  i side: objs (P, n_obj) f32, viol (P, 1) f32;
+  j side: objs.T (n_obj, P) f32, viol.T (1, P) f32, active (1, P) i32 mask --
+  only active *dominators* are counted (every row of the output is computed).
 
 Block layout is 2-D-friendly: the comparison tile is ``(tile, j_tile)`` with
 the **dominator** (j) axis innermost, so with the registry default
@@ -46,7 +47,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from . import registry
 
@@ -54,33 +54,36 @@ __all__ = ["dominance_counts_pallas"]
 
 
 def _kernel(oi_ref, vi_ref, oj_ref, vj_ref, aj_ref, out_ref, *, n_obj: int):
-    """One (i, j) step: count active j-tile dominators of each i-tile point."""
+    """One (i, j) step: count active j-tile dominators of each i-tile point.
+
+    The i side arrives as columns ((Ti, n_obj), (Ti, 1)) and the j side as
+    lane rows ((n_obj, Tj), (1, Tj)), so every comparison is a plain
+    (Ti, 1) x (1, Tj) broadcast with no relayout.
+    """
     j = pl.program_id(1)
 
-    vi = vi_ref[...][:, 0]                       # (Ti,)
-    vj = vj_ref[...][:, 0]                       # (Tj,)
+    vi = vi_ref[...]                             # (Ti, 1)
+    vj = vj_ref[...]                             # (1, Tj)
     fi = vi <= 0.0
     fj = vj <= 0.0
 
     le = None
     lt = None
     for k in range(n_obj):                       # static unroll over objectives
-        ok_i = oi_ref[...][:, k]                 # (Ti,)
-        ok_j = oj_ref[...][:, k]                 # (Tj,)
-        le_k = ok_j[None, :] <= ok_i[:, None]    # (Ti, Tj): j lanes innermost
-        lt_k = ok_j[None, :] < ok_i[:, None]
+        ok_i = oi_ref[:, pl.ds(k, 1)]            # (Ti, 1)
+        ok_j = oj_ref[pl.ds(k, 1), :]            # (1, Tj)
+        le_k = ok_j <= ok_i                      # (Ti, Tj): j lanes innermost
+        lt_k = ok_j < ok_i
         le = le_k if le is None else le & le_k
         lt = lt_k if lt is None else lt | lt_k
 
     obj_dom = le & lt
-    both_feas = fi[:, None] & fj[None, :]
-    both_infeas = (~fi)[:, None] & (~fj)[None, :]
-    dom = (both_feas & obj_dom)
-    dom |= (~fi)[:, None] & fj[None, :]
-    dom |= both_infeas & (vj[None, :] < vi[:, None])
+    dom = fi & fj & obj_dom
+    dom |= ~fi & fj
+    dom |= ~fi & ~fj & (vj < vi)
 
-    act = aj_ref[...][:, 0] != 0                 # (Tj,)
-    part = (dom & act[None, :]).astype(jnp.int32).sum(axis=1)[:, None]
+    act = aj_ref[...] != 0                       # (1, Tj)
+    part = (dom & act).astype(jnp.int32).sum(axis=1, keepdims=True)
 
     @pl.when(j == 0)
     def _init():
@@ -115,8 +118,8 @@ def dominance_counts_pallas(
     tile, j_tile = min(tile, p), min(j_tile, p)
     assert p % tile == 0, (p, tile)
     assert p % j_tile == 0, (p, j_tile)
+    objs = objs.astype(jnp.float32)
     v2 = viol.astype(jnp.float32).reshape(p, 1)
-    a2 = active.astype(jnp.int32).reshape(p, 1)
 
     cost = spec.cost_estimate(p=p, n_obj=n_obj)
     params = spec.compiler_params(tile=tile, j_tile=j_tile, n_obj=n_obj)
@@ -127,14 +130,14 @@ def dominance_counts_pallas(
         in_specs=[
             pl.BlockSpec((tile, n_obj), lambda i, j: (i, 0)),
             pl.BlockSpec((tile, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((j_tile, n_obj), lambda i, j: (j, 0)),
-            pl.BlockSpec((j_tile, 1), lambda i, j: (j, 0)),
-            pl.BlockSpec((j_tile, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((n_obj, j_tile), lambda i, j: (0, j)),
+            pl.BlockSpec((1, j_tile), lambda i, j: (0, j)),
+            pl.BlockSpec((1, j_tile), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((tile, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((p, 1), jnp.int32),
         cost_estimate=pl.CostEstimate(**cost),
-        compiler_params=pltpu.TPUCompilerParams(**params),
+        compiler_params=params,
         interpret=interpret,
-    )(objs.astype(jnp.float32), v2, objs.astype(jnp.float32), v2, a2)
+    )(objs, v2, objs.T, v2.T, active.astype(jnp.int32).reshape(1, p))
     return out[:, 0]

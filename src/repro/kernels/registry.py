@@ -13,9 +13,10 @@ This module is the single place every implementation registers:
     engine's int32-safe ``a_tile``),
   * a **constraint** predicate filtering candidates per shape bucket (int32
     partial-sum bounds, divisibility, VMEM fit),
-  * **cost-estimate** and **compiler-params** formulas (plain dicts; the
-    kernel files wrap them into ``pl.CostEstimate`` /
-    ``pltpu.TPUCompilerParams`` -- dimension semantics + VMEM limits),
+  * **cost-estimate** and **compiler-params** formulas (the cost dict is
+    wrapped into ``pl.CostEstimate`` by the kernel files; the params dict --
+    dimension semantics + VMEM limits -- into ``pltpu.CompilerParams`` by
+    :meth:`KernelSpec.compiler_params`, the one place that class is built),
   * a **correctness oracle** (the reference implementation every tuned tile
     candidate must match bit-for-bit under interpret mode; see
     ``kernels.tuning``).
@@ -78,8 +79,8 @@ class KernelSpec:
     checked against.  ``tunables`` is the ordered block-shape search space;
     ``defaults_fn(bucket)`` the safe (untuned) tiles; ``constraint(bucket,
     tiles)`` filters candidates; ``cost_fn`` / ``params_fn`` return plain
-    dicts the kernel files wrap into ``pl.CostEstimate`` and
-    ``pltpu.TPUCompilerParams``.
+    dicts: the kernel files wrap the first into ``pl.CostEstimate``, and
+    :meth:`compiler_params` wraps the second into ``pltpu.CompilerParams``.
     """
 
     name: str                                   # "fastchar.pallas", ...
@@ -144,8 +145,13 @@ class KernelSpec:
     def cost_estimate(self, **shape) -> dict | None:
         return None if self.cost_fn is None else self.cost_fn(**shape)
 
-    def compiler_params(self, **shape) -> dict | None:
-        return None if self.params_fn is None else self.params_fn(**shape)
+    def compiler_params(self, **shape):
+        """``pltpu.CompilerParams`` for ``shape`` (None without a params_fn)."""
+        if self.params_fn is None:
+            return None
+        from jax.experimental.pallas import tpu as pltpu
+
+        return pltpu.CompilerParams(**self.params_fn(**shape))
 
 
 # ---------------------------------------------------------------------------
@@ -211,6 +217,28 @@ def describe() -> str:
 # needs the operator model imports it lazily (numpy-only, no JAX).
 
 
+# v5e's default scoped-VMEM limit: a kernel is never given less than the
+# compiler would give it without a limit.
+_SCOPED_VMEM = 16 << 20
+# what one kernel's blocks and temporaries may occupy of a core's 128 MiB VMEM
+_VMEM_FIT = 64 << 20
+
+
+def _vmem_bytes(*shape: int, itemsize: int = 4) -> int:
+    """VMEM bytes of one buffer of ``shape``: the minor dim pads to whole
+    128-lane tiles and the second-minor to whole 8-sublane tiles."""
+    *lead, sub, lane = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    n = 1
+    for x in lead:
+        n *= x
+    return itemsize * n * (-(-sub // 8) * 8) * (-(-lane // 128) * 128)
+
+
+def _vmem_limit(blocks: int, temps: int = 0) -> int:
+    """Scoped-VMEM limit for double-buffered ``blocks`` plus ``temps``."""
+    return max(_SCOPED_VMEM, 2 * blocks + temps)
+
+
 def _char_bound(n_bits: int) -> int:
     from repro.core.operator_model import spec_for
 
@@ -256,11 +284,14 @@ def _char_cost(*, rows: int, d: int, a: int, b: int, a_tile: int, **_) -> dict:
 
 
 def _char_params(*, rows: int, d_block: int, a_tile: int, b: int, **_) -> dict:
-    block_bytes = 4 * (rows * d_block * 4 * b + 2 * a_tile * b + d_block * a_tile * b)
+    blocks = (rows * d_block * _vmem_bytes(4, b) + 2 * _vmem_bytes(a_tile, b)
+              + 2 * _vmem_bytes(d_block, 8))
+    # the per-row pair selectors plus one config's error-tile temporaries
+    temps = (rows + 10) * _vmem_bytes(a_tile, b)
     return {
         # output blocks are disjoint across both grid axes
         "dimension_semantics": ("parallel", "parallel"),
-        "vmem_limit_bytes": max(4 << 20, 2 * block_bytes),
+        "vmem_limit_bytes": _vmem_limit(blocks, temps),
     }
 
 
@@ -278,11 +309,13 @@ def _entry_char_cost(*, rows: int, d: int, a: int, b: int, a_tile: int,
 
 
 def _entry_char_params(*, rows: int, d_block: int, a_tile: int, b: int, **_) -> dict:
-    # masks block + the synthesized per-row planes + the reconstructed tile
-    block_bytes = 4 * (d_block * rows + d_block * 4 * b + d_block * a_tile * b)
+    # the masks block lives in SMEM; VMEM holds the two partial blocks, the
+    # synthesized planes and one config's error-tile temporaries
+    blocks = 2 * _vmem_bytes(d_block, 8)
+    temps = (rows + 12) * _vmem_bytes(a_tile, b) + 8 * _vmem_bytes(1, b)
     return {
         "dimension_semantics": ("parallel", "parallel"),
-        "vmem_limit_bytes": max(4 << 20, 2 * block_bytes),
+        "vmem_limit_bytes": _vmem_limit(blocks, temps),
     }
 
 
@@ -296,14 +329,44 @@ def _app_bucket(*, n_bits: int, d: int, m: int, k: int, n: int):
     )
 
 
+def _gemv_blocks(*, m: int, k_tile: int, n: int) -> tuple[int, int]:
+    """(VMEM blocks, temporaries) of one GEMV grid step shared by both app
+    kernels: the A/B code tiles and the output block, plus the pair masks,
+    the lane-gathered plane tile and the per-row partials."""
+    blocks = (_vmem_bytes(m, k_tile) + _vmem_bytes(k_tile, n)
+              + _vmem_bytes(m, n))
+    temps = (2 * _vmem_bytes(m, k_tile) + 3 * _vmem_bytes(k_tile, 128)
+             + 2 * _vmem_bytes(m, 128) + 3 * _vmem_bytes(m, n))
+    return blocks, temps
+
+
+def _gemv_tile_ok(bucket, k_tile: int) -> bool:
+    _, _, _, k, _ = bucket
+    # never wider than the padded K; k_tile is the lane dim of the A tile,
+    # so it is whole 128-lane tiles or the whole (padded) K
+    return k_tile <= k and (k_tile % 128 == 0 or k_tile == k)
+
+
+def _row_table_bytes(n_bits: int) -> int:
+    from repro.core.operator_model import spec_for
+
+    spec = spec_for(n_bits)
+    return 4 * 2 * 4 * spec.n_inputs * spec.n_row_masks
+
+
 def _app_constraint(bucket, tiles) -> bool:
     n_bits, d, m, k, n = bucket
     k_tile = tiles["k_tile"]
-    if k_tile > _pow2_bucket(k):  # never tile wider than the padded K
+    if not _gemv_tile_ok(bucket, k_tile):
         return False
-    a = 1 << n_bits
-    # VMEM fit: the resident flattened table + the (M, k_tile, N) gather tile
-    return 4 * (a * a + m * k_tile * n + m * k_tile + k_tile * n) < (12 << 20)
+    # the per-row planes are gathered from the RowTables constant, which
+    # grows as 2^(2N+4) bytes: 4 MiB at 8 bits, 1 GiB at 12
+    if _row_table_bytes(n_bits) > _VMEM_FIT:
+        return False
+    rows = -(-n_bits // 2)
+    blocks, temps = _gemv_blocks(m=m, k_tile=k_tile, n=n)
+    blocks += rows * _vmem_bytes(4, 1 << n_bits)
+    return 2 * blocks + temps < _VMEM_FIT
 
 
 def _app_xla_constraint(bucket, tiles) -> bool:
@@ -315,59 +378,61 @@ def _app_xla_constraint(bucket, tiles) -> bool:
 def _entry_app_constraint(bucket, tiles) -> bool:
     n_bits, d, m, k, n = bucket
     k_tile = tiles["k_tile"]
-    if k_tile > _pow2_bucket(k):
+    if not _gemv_tile_ok(bucket, k_tile):
         return False
-    a = 1 << n_bits
-    # VMEM fit: one row's synthesized (4, B) planes + the gather tile -- no
-    # (A, B) table, which is what admits 12-bit operands the table kernel
-    # cannot hold (a*a ints would be 67 MB there)
-    return 4 * (4 * a + m * k_tile * n + m * k_tile + k_tile * n) < (12 << 20)
+    # VMEM fit: one row's synthesized planes + the GEMV tiles -- no row
+    # tables, which is what admits 12-bit operands the table kernel cannot
+    # stage
+    blocks, temps = _gemv_blocks(m=m, k_tile=k_tile, n=n)
+    temps += 8 * _vmem_bytes(1, 1 << n_bits)
+    return 2 * blocks + temps < _VMEM_FIT
 
 
 def _entry_app_cost(*, d: int, m: int, k: int, n: int, a: int, rows: int,
                     width: int, **_) -> dict:
     return {
-        # R gather-accumulate passes over the (M, K, N) tensor + the per-grid-
-        # step synthesis (R*4 chains of `width` steps over the B axis; one
-        # grid step per default-width K tile)
-        "flops": 2 * d * m * k * n * rows
-        + d * max(1, k // 64) * rows * 4 * a * width * 6,
+        # the table kernel's R*4 mask GEMMs + the per-grid-step synthesis
+        # (R*4 chains of `width` steps over the B axis; one grid step per
+        # default-width K tile)
+        "flops": 8 * d * m * k * n * rows
+        + d * max(1, k // 128) * rows * 4 * a * width * 6,
         "bytes_accessed": 4 * (d * rows + m * k + k * n + d * m * n),
         "transcendentals": 0,
     }
 
 
-def _entry_app_params(*, m: int, k_tile: int, n: int, a: int, rows: int, **_) -> dict:
-    block_bytes = 4 * (rows + 4 * a + m * k_tile * n + m * k_tile + k_tile * n + m * n)
+def _entry_app_params(*, m: int, k_tile: int, n: int, a: int, **_) -> dict:
+    blocks, temps = _gemv_blocks(m=m, k_tile=k_tile, n=n)
     return {
         "dimension_semantics": ("parallel", "arbitrary"),
-        "vmem_limit_bytes": max(4 << 20, 2 * block_bytes),
+        "vmem_limit_bytes": _vmem_limit(blocks, temps + 8 * _vmem_bytes(1, a)),
     }
 
 
 def _app_defaults(bucket) -> dict:
     _, _, _, k, _ = bucket
-    return {"k_tile": min(64, _pow2_bucket(k))}
+    return {"k_tile": min(128, _pow2_bucket(k))}
 
 
 def _app_xla_defaults(bucket) -> dict:
     return {"d_chunk": min(8, bucket[1])}
 
 
-def _app_cost(*, d: int, m: int, k: int, n: int, a: int, **_) -> dict:
+def _app_cost(*, d: int, m: int, k: int, n: int, a: int, rows: int, **_) -> dict:
     return {
-        "flops": 2 * d * m * k * n,
-        "bytes_accessed": 4 * (d * a * a + m * k + k * n + d * m * n),
+        # R*4 (M, K) x (K, N) mask GEMMs per config
+        "flops": 8 * d * m * k * n * rows,
+        "bytes_accessed": 4 * (d * rows * 4 * a + m * k + k * n + d * m * n),
         "transcendentals": 0,
     }
 
 
-def _app_params(*, m: int, k_tile: int, n: int, a: int, **_) -> dict:
-    block_bytes = 4 * (a * a + m * k_tile * n + m * k_tile + k_tile * n + m * n)
+def _app_params(*, m: int, k_tile: int, n: int, a: int, rows: int, **_) -> dict:
+    blocks, temps = _gemv_blocks(m=m, k_tile=k_tile, n=n)
     return {
         # the k axis accumulates into a revisited output block: sequential
         "dimension_semantics": ("parallel", "arbitrary"),
-        "vmem_limit_bytes": max(4 << 20, 2 * block_bytes),
+        "vmem_limit_bytes": _vmem_limit(blocks + rows * _vmem_bytes(4, a), temps),
     }
 
 
@@ -389,7 +454,7 @@ def _axo_constraint(bucket, tiles) -> bool:
         return False
     # VMEM fit: a/b value blocks + the rank-stacked factor blocks + f32
     # accumulator scratch and output block
-    return 4 * ((1 + rank) * (bm * bk + bk * bn) + 2 * bm * bn) < (12 << 20)
+    return 2 * _axo_blocks(bm=bm, bn=bn, bk=bk, rank=rank) < _VMEM_FIT
 
 
 def _axo_defaults(bucket) -> dict:
@@ -406,12 +471,19 @@ def _axo_cost(*, m: int, k: int, n: int, rank: int, **_) -> dict:
     }
 
 
+def _axo_blocks(*, bm: int, bn: int, bk: int, rank: int) -> int:
+    return ((1 + rank) * (_vmem_bytes(bm, bk) + _vmem_bytes(bk, bn))
+            + _vmem_bytes(bm, bn))
+
+
 def _axo_params(*, bm: int, bn: int, bk: int, rank: int, **_) -> dict:
-    block_bytes = 4 * ((1 + rank) * (bm * bk + bk * bn) + 2 * bm * bn)
+    # + the accumulator scratch and the step's (bm, bn) partial products
+    temps = 3 * _vmem_bytes(bm, bn)
     return {
         # the K axis accumulates into a revisited output block: sequential
         "dimension_semantics": ("parallel", "parallel", "arbitrary"),
-        "vmem_limit_bytes": max(4 << 20, 2 * block_bytes),
+        "vmem_limit_bytes": _vmem_limit(
+            _axo_blocks(bm=bm, bn=bn, bk=bk, rank=rank), temps),
     }
 
 
@@ -425,7 +497,8 @@ def _flash_constraint(bucket, tiles) -> bool:
     if bq > max(8, sq) or bk > max(128, skv):
         return False
     # q/acc/o blocks + k/v blocks + the (bq, bk) score matrix and m/l rows
-    return 4 * (3 * bq * hd + 2 * bk * hd + 2 * bq * bk + 2 * bq) < (12 << 20)
+    blocks, temps = _flash_blocks(bq=bq, bk=bk, hd=hd)
+    return 2 * blocks + temps < _VMEM_FIT
 
 
 def _flash_defaults(bucket) -> dict:
@@ -443,12 +516,19 @@ def _flash_cost(*, b: int, h: int, sq: int, skv: int, hd: int,
     }
 
 
+def _flash_blocks(*, bq: int, bk: int, hd: int) -> tuple[int, int]:
+    """(q/k/v/o blocks, m/l/acc scratch + score temporaries)."""
+    blocks = 2 * _vmem_bytes(bq, hd) + 2 * _vmem_bytes(bk, hd)
+    temps = (2 * _vmem_bytes(bq) + _vmem_bytes(bq, hd)
+             + 3 * _vmem_bytes(bq, bk))
+    return blocks, temps
+
+
 def _flash_params(*, bq: int, bk: int, hd: int, **_) -> dict:
-    block_bytes = 4 * (3 * bq * hd + 2 * bk * hd + 2 * bq * bk + 2 * bq)
     return {
         # KV blocks revisit the q block's scratch (online softmax): sequential
         "dimension_semantics": ("parallel", "parallel", "parallel", "arbitrary"),
-        "vmem_limit_bytes": max(4 << 20, 2 * block_bytes),
+        "vmem_limit_bytes": _vmem_limit(*_flash_blocks(bq=bq, bk=bk, hd=hd)),
     }
 
 
@@ -459,7 +539,9 @@ def _moo_bucket(*, p: int, n_obj: int):
 def _moo_constraint(bucket, tiles) -> bool:
     p, _ = bucket
     tile, j_tile = tiles["tile"], tiles["j_tile"]
-    return tile <= p and j_tile <= p
+    # j_tile is the lane dim of the dominator rows: whole 128-lane tiles or
+    # the whole (padded) population
+    return tile <= p and j_tile <= p and (j_tile % 128 == 0 or j_tile == p)
 
 
 def _moo_defaults(bucket) -> dict:
@@ -477,11 +559,15 @@ def _moo_cost(*, p: int, n_obj: int, **_) -> dict:
 
 
 def _moo_params(*, tile: int, j_tile: int, n_obj: int, **_) -> dict:
-    block_bytes = 4 * (2 * (tile + j_tile) * (n_obj + 2) + tile * j_tile)
+    # i columns (padded to 128 lanes), j rows (padded to 8 sublanes), output
+    blocks = (_vmem_bytes(tile, n_obj) + 2 * _vmem_bytes(tile, 1)
+              + _vmem_bytes(n_obj, j_tile) + 2 * _vmem_bytes(1, j_tile))
+    # the (tile, j_tile) comparison masks
+    temps = (2 * n_obj + 6) * _vmem_bytes(tile, j_tile)
     return {
         # j revisits the output block (accumulation): sequential
         "dimension_semantics": ("parallel", "arbitrary"),
-        "vmem_limit_bytes": max(4 << 20, 2 * block_bytes),
+        "vmem_limit_bytes": _vmem_limit(blocks, temps),
     }
 
 
@@ -587,7 +673,9 @@ register(KernelSpec(
     impl="pallas",
     fn_ref="repro.kernels.tuning:_run_fastapp",
     oracle_ref="repro.kernels.tuning:_oracle_fastapp",
-    tunables=(("k_tile", (16, 32, 64, 128, 256)),),
+    # whole 128-lane tiles (_gemv_tile_ok); a K < 128 bucket has no
+    # candidates and runs its default, the whole K
+    tunables=(("k_tile", (128, 256)),),
     defaults_fn=_app_defaults,
     bucket_fn=_app_bucket,
     constraint=_app_constraint,
@@ -615,7 +703,7 @@ register(KernelSpec(
     impl="entry_pallas",
     fn_ref="repro.kernels.tuning:_run_fastapp",
     oracle_ref="repro.kernels.tuning:_oracle_fastapp",
-    tunables=(("k_tile", (16, 32, 64, 128, 256)),),
+    tunables=(("k_tile", (128, 256)),),
     defaults_fn=_app_defaults,
     bucket_fn=_app_bucket,
     constraint=_entry_app_constraint,

@@ -9,7 +9,7 @@ from jax.sharding import PartitionSpec as P
 from ..configs.base import ModelConfig, ShapeConfig
 from ..configs.registry import input_specs
 from ..models.model import model_spec
-from ..models.sharding import ShardingRules, named_sharding, set_mesh
+from ..models.sharding import ShardingRules, named_sharding
 from ..models.spec import abstract_params, param_shardings
 from ..optim import cosine_schedule, make_optimizer
 from .steps import (
@@ -31,7 +31,7 @@ def lower_step(cfg: ModelConfig, shape: ShapeConfig, mesh, rules: ShardingRules)
     p_sh = param_shardings(spec, rules, mesh)
     specs = input_specs(cfg, shape)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt = make_optimizer(cfg.optimizer, cosine_schedule(3e-4))
             o_spec = opt.state_spec(spec)

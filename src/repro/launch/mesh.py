@@ -25,4 +25,6 @@ def make_production_mesh(*, multi_pod: bool = False):
             "did you forget XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "(set as the very first line of dryrun.py)?"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices[:need])

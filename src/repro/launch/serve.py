@@ -9,6 +9,7 @@ optionally with AxO-approximate arithmetic deployed in every linear layer
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 
 import jax
@@ -24,6 +25,7 @@ from ..models.model import model_spec
 from ..models.sharding import BASE_RULES
 from ..models.spec import init_params
 from ..obs import telemetry as obs
+from .compile_cache import enable_compile_cache
 from .steps import make_decode_step, make_prefill_step
 
 
@@ -40,7 +42,9 @@ def demo_operator(rank: int) -> AxOOperator:
     return AxOOperator.from_config(op_cfg, rank=rank)
 
 
-def main(argv=None):
+def main(argv=None) -> dict:
+    """Serve the requests; returns what it measured (timings, the AxO fidelity
+    numbers and the DSE smoke results) for callers that check it."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=sorted(ARCH_IDS))
     ap.add_argument("--batch", type=int, default=4)
@@ -89,6 +93,7 @@ def main(argv=None):
         args.dse_service = True
     if args.dse_service and args.metrics_port is None:
         ap.error("--dse-service requires --metrics-port")
+    enable_compile_cache()
 
     # one sink for the whole driver: prefill/decode latency histograms and
     # tokens/sec gauges always collect (counters chain to the process
@@ -205,6 +210,7 @@ def main(argv=None):
     print(f"arch={cfg.name} prefill({args.batch}x{args.prompt_len})="
           f"{t_prefill*1e3:.1f}ms decode({args.gen - 1} steps)={t_decode*1e3:.1f}ms")
     print("generated token ids (row 0):", np.asarray(out[0]).tolist())
+    report = {"arch": cfg.name, "prefill_s": t_prefill, "decode_s": t_decode}
     if metrics is not None:
         metrics.set_deployment({"mode": "exact", "arch": cfg.name})
 
@@ -216,8 +222,8 @@ def main(argv=None):
         impl = args.axo_impl or ("pallas" if on_tpu() else "xla")
         dep = deploy_axo(params, op, cfg, layers=tuple(args.axo_layers),
                          impl=impl)
-        pre_a = jax.jit(make_prefill_step(cfg, rules, max_seq=max_seq, axo=dep))
-        dec_a = jax.jit(make_decode_step(cfg, rules, axo=dep))
+        pre_a = functools.partial(prefill, axo=dep)
+        dec_a = functools.partial(decode, axo=dep)
         out_a, _, _ = serve(pre_a, dec_a, label="axo")  # warm + free-run tokens
         _, axo_lgs, (tp, td) = serve(pre_a, dec_a, label="axo")
 
@@ -242,6 +248,9 @@ def main(argv=None):
               f"prefill={tp*1e3:.1f}ms decode={td*1e3:.1f}ms  "
               f"free-run match={match:.2%} teacher-forced top1={top1:.2%} "
               f"logit rel_err={rel:.4f}")
+        report["axo"] = {"impl": impl, "projections": dep.n_entries,
+                         "top1": top1, "free_run_match": match,
+                         "logit_rel_err": rel, "prefill_s": tp, "decode_s": td}
         tel.gauge("serve.axo_top1", top1)
         tel.gauge("serve.axo_free_run_match", match)
         tel.gauge("serve.axo_logit_rel_err", rel)
@@ -272,6 +281,7 @@ def main(argv=None):
 
         t0 = time.perf_counter()
         jobs = []
+        report["dse"] = []
         for i in range(args.dse_smoke):
             body = _json.dumps({
                 "n_bits": 4, "const_sf": 0.5 + 0.3 * (i % 2), "seed": i // 2,
@@ -289,6 +299,7 @@ def main(argv=None):
                 res = _json.loads(resp.read())
             if res["status"] != "done":
                 raise RuntimeError(f"dse smoke: {jid} -> {res}")
+            report["dse"].append(res)
             print(f"dse {jid}: const_sf={res['request']['const_sf']} "
                   f"seed={res['request']['seed']} hv={res['hv_vpf']:.4g} "
                   f"front={len(res['front'])}")
@@ -303,8 +314,8 @@ def main(argv=None):
         dse_queue.close()
     if metrics is not None:
         metrics.stop()
-    return 0
+    return report
 
 
 if __name__ == "__main__":
-    raise SystemExit(main())
+    main()

@@ -138,21 +138,22 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=jnp.bfloat16):
     return init_params(cache_spec(cfg, batch, max_seq), dtype=dtype)  # all zeros
 
 
-def make_prefill_step(cfg: ModelConfig, rules: ShardingRules, max_seq: int,
-                      axo=None):
-    """(params, tokens[, frontend embeds]) -> (last-position logits, cache).
+def make_prefill_step(cfg: ModelConfig, rules: ShardingRules, max_seq: int):
+    """(params, tokens[, frontend embeds][, axo=]) -> (last-position logits, cache).
 
     ``frontend`` is the stubbed modality input -- frame embeddings for the
     enc-dec family, patch embeddings for the VLM family (cfg decides which).
     The cache is created inside the step (zeros) at capacity ``max_seq`` and
     filled by the prefill pass -- one compiled program per (batch, capacity).
 
-    ``axo`` (an ``axo.deploy.AxODeployment``) is closed over: its cached weight
-    codes/factors become jit constants, so the compiled step serves every token
-    through the approximate operator with no per-call requantization.
+    ``axo`` (an ``axo.deploy.AxODeployment``, a pytree) is a step argument:
+    the compiled step serves every token through the approximate operator
+    from the deployment's cached weight codes/factors, with no per-call
+    requantization and without embedding them in the program.  Bind it with
+    ``functools.partial(jax.jit(step), axo=dep)``.
     """
 
-    def prefill_step(params, tokens, frontend=None):
+    def prefill_step(params, tokens, frontend=None, axo=None):
         from ..obs.telemetry import note_trace
 
         note_trace("launch.prefill_step")  # runs once per (re)trace
@@ -171,12 +172,11 @@ def make_prefill_step(cfg: ModelConfig, rules: ShardingRules, max_seq: int,
     return prefill_step
 
 
-def make_decode_step(cfg: ModelConfig, rules: ShardingRules, axo=None):
-    """(params, cache, tokens (B,1), index ()) -> (logits (B,1,V), new cache).
+def make_decode_step(cfg: ModelConfig, rules: ShardingRules):
+    """(params, cache, tokens (B,1), index ()[, axo=]) -> (logits (B,1,V),
+    new cache).  ``axo`` as in :func:`make_prefill_step`."""
 
-    ``axo`` as in :func:`make_prefill_step`."""
-
-    def decode_step(params, cache, tokens, index):
+    def decode_step(params, cache, tokens, index, axo=None):
         from ..obs.telemetry import note_trace
 
         note_trace("launch.decode_step")  # runs once per (re)trace

@@ -33,7 +33,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig
 from .layers import mlp_apply, mlp_spec
-from .sharding import ShardingRules, constrain, _current_mesh, shard_map
+from .sharding import ShardingRules, constrain, _current_mesh
 from .spec import ParamSpec
 
 __all__ = ["moe_spec", "moe_apply", "moe_capacity"]
@@ -257,7 +257,7 @@ def moe_apply(
         ).reshape(b, s, d)
     elif decode_ws:
         cap = moe_capacity(t, cfg)
-        out = shard_map(
+        out = jax.shard_map(
             partial(_ep_decode_body, cfg, cap),
             mesh=mesh,
             in_specs=(
@@ -269,7 +269,7 @@ def moe_apply(
                 P(None, None, None),           # gates
             ),
             out_specs=P(None, None, "data"),
-            check=False,
+            check_vma=False,
         )(p["w_gate"], p["w_up"], p["w_down"], x, top_i, gates)
     elif ep_ok:
         ep = mesh.shape["model"]
@@ -279,7 +279,7 @@ def moe_apply(
         )
         cap = moe_capacity(t // data_n_tok, cfg)
         tok_spec = P(bspec, None, None)
-        out = shard_map(
+        out = jax.shard_map(
             partial(_ep_body, cfg, cap),
             mesh=mesh,
             in_specs=(
@@ -291,7 +291,7 @@ def moe_apply(
                 tok_spec,                 # gates
             ),
             out_specs=tok_spec,
-            check=False,
+            check_vma=False,
         )(p["w_gate"], p["w_up"], p["w_down"], x, top_i, gates)
     else:
         cap = moe_capacity(t, cfg)

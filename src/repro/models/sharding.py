@@ -16,7 +16,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
     "ShardingRules", "BASE_RULES", "logical_pspec", "constrain",
-    "named_sharding", "set_mesh",
+    "named_sharding",
 ]
 
 MeshAxes = tuple[str, ...]
@@ -135,56 +135,13 @@ def named_sharding(mesh: Mesh, spec: P, shape: tuple[int, ...] | None = None) ->
 def constrain(x, rules: ShardingRules, *axes: str | None):
     """with_sharding_constraint via logical activation axes (no-op off-mesh)."""
     mesh = _current_mesh()
-    if mesh is None or mesh.empty:
+    if mesh is None:
         return x
     spec = rules.resolve(tuple(axes), kind="act")
     return jax.lax.with_sharding_constraint(x, named_sharding(mesh, spec))
 
 
-def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` portable across JAX versions.
-
-    Newer JAX hoists shard_map to the top level with a ``check_vma`` flag; on
-    0.4.x it lives in ``jax.experimental.shard_map`` with ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
-    )
-
-
-def set_mesh(mesh: Mesh):
-    """Ambient-mesh context manager, portable across JAX versions.
-
-    Newer JAX exposes ``jax.set_mesh``; older releases (e.g. 0.4.x) only have
-    the ``with mesh:`` thread-resources context, which ``_current_mesh`` below
-    also recognizes.  A ``Mesh`` is itself a context manager, so returning it
-    directly gives the fallback.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def _current_mesh() -> Mesh | None:
-    """Mesh in scope: ``with mesh:`` (thread resources) or ``use_mesh`` (abstract)."""
-    try:
-        from jax._src import mesh as mesh_lib
-
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+def _current_mesh():
+    """The mesh ``jax.set_mesh`` put in scope, or None outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m

@@ -166,24 +166,23 @@ def _char_inputs(n_bits: int):
 
 
 def _app_inputs(n_bits: int):
-    """(tables_flat, a_codes, b_codes) for table_gemv_pallas."""
+    """(small, a_codes, b_codes) for table_gemv_pallas."""
     import numpy as np
 
     import jax.numpy as jnp
 
-    from ..apps.fastapp import product_tables_jax
-    from ..core.operator_model import spec_for
+    from ..core.fastchar import _gather_small
+    from ..core.operator_model import config_to_masks, spec_for
 
     spec = spec_for(n_bits)
     rng = np.random.default_rng(1)
     cfgs = rng.integers(0, 2, (4, spec.n_luts)).astype(np.uint8)
-    tables = product_tables_jax(spec, cfgs)             # (D, A, B)
-    d = tables.shape[0]
-    tables_flat = tables.reshape(d, -1)
+    masks = config_to_masks(spec, cfgs).astype(np.int32)
+    small = _gather_small(jnp.asarray(masks), n_bits)   # (R, D, 4, B)
     m, k, n = 8, 16, 8
     a = jnp.asarray(rng.integers(0, spec.n_inputs, (m, k)), jnp.int32)
     b = jnp.asarray(rng.integers(0, spec.n_inputs, (k, n)), jnp.int32)
-    return tables_flat, a, b
+    return small, a, b
 
 
 def _moo_inputs(p: int = 128, n_obj: int = 2):
@@ -242,9 +241,9 @@ def profile_registry(tel=None, n_bits: int = 8,
     records.append(check_estimate(rec, est, tel=tel))
 
     # fastapp: table-GEMV
-    tables_flat, ac, bc = _app_inputs(n_bits)
+    small, ac, bc = _app_inputs(n_bits)
     spec = registry.get("fastapp.pallas")
-    d = int(tables_flat.shape[0])
+    rows, d = int(small.shape[0]), int(small.shape[1])
     m, k = int(ac.shape[0]), int(ac.shape[1])
     n = int(bc.shape[1])
     bucket = spec.bucket(n_bits=n_bits, d=d, m=m, k=k, n=n)
@@ -252,9 +251,10 @@ def profile_registry(tel=None, n_bits: int = 8,
     tiles["k_tile"] = min(tiles["k_tile"], k)
     rec = profile_fn(
         functools.partial(table_gemv_pallas, interpret=interpret, **tiles),
-        tables_flat, ac, bc, name="fastapp.pallas", tel=tel,
+        small, ac, bc, name="fastapp.pallas", tel=tel,
     )
-    est = spec.cost_estimate(d=d, m=m, k=k, n=n, a=1 << n_bits, **tiles)
+    est = spec.cost_estimate(d=d, m=m, k=k, n=n, a=1 << n_bits, rows=rows,
+                             **tiles)
     records.append(check_estimate(rec, est, tel=tel))
 
     # fastmoo: dominance counts

@@ -2,6 +2,8 @@
 whole-model deployment entry structure, and generation fidelity of a
 fully-deployed reduced LM vs the exact serving path."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -126,6 +128,26 @@ def test_deployment_entries_cache_weight_factors():
     assert dep.head["gb"].shape[0] == 3
 
 
+def test_deployment_enters_steps_as_an_argument():
+    """The deployment is a pytree argument of the jitted steps: none of its
+    entries is captured as a program constant (at published widths they are
+    gigabytes, which the compiler would copy on the host)."""
+    cfg, params = _granite()
+    dep = deploy_axo(params, _mild_op(rank=2), cfg, impl="xla")
+    n_dep = len(jax.tree.leaves(dep))
+    assert n_dep == 2 + 3 * dep.n_entries   # tables + (bv, gb, scale) each
+    toks = jnp.zeros((2, 8), jnp.int32)
+    step = make_prefill_step(cfg, BASE_RULES, max_seq=8)
+    closed = jax.make_jaxpr(step)(params, toks, axo=dep)
+    assert len(closed.in_avals) == len(jax.tree.leaves(params)) + 1 + n_dep
+    const_bytes = sum(np.asarray(c).nbytes for c in closed.consts)
+    assert const_bytes < dep.stages["0"]["0"]["mixer"]["wq"]["bv"].nbytes
+    # rebuilt from its leaves, it keeps the static operator and layout
+    leaves, tree = jax.tree.flatten(dep)
+    again = jax.tree.unflatten(tree, leaves)
+    assert again.op is dep.op and again.n_entries == dep.n_entries
+
+
 def test_head_apply_matches_axo_linear():
     """dep.apply on the cached head entry == axo_linear on the raw weight."""
     cfg, params = _granite()
@@ -211,8 +233,8 @@ def test_fully_deployed_generation_tracks_exact():
     dep = deploy_axo(params, _mild_op(rank=16), cfg,
                      layers=AXO_LAYERS, impl="xla")
     assert dep.n_entries == 8
-    pre_a = jax.jit(make_prefill_step(cfg, rules, max_seq=max_seq, axo=dep))
-    dec_a = jax.jit(make_decode_step(cfg, rules, axo=dep))
+    pre_a = functools.partial(prefill, axo=dep)
+    dec_a = functools.partial(decode, axo=dep)
     rep = _replay(pre_a, dec_a, params, toks, exact_toks)
     top1 = float(np.mean([
         (jnp.argmax(a, -1) == jnp.argmax(e, -1)).mean()

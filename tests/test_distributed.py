@@ -29,9 +29,11 @@ def test_moe_ep_shard_map_matches_reference():
     """Expert-parallel shard_map MoE == single-device reference dispatch."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import AxisType
+        AUTO = AxisType.Auto
         from repro.configs.registry import get_arch
         from repro.models.moe import moe_spec, moe_apply
-        from repro.models.sharding import BASE_RULES, set_mesh
+        from repro.models.sharding import BASE_RULES
         from repro.models.spec import init_params
 
         cfg = get_arch("jamba-v0.1-52b").reduced()   # 8 experts top-2
@@ -41,8 +43,8 @@ def test_moe_ep_shard_map_matches_reference():
 
         ref, aux_ref = moe_apply(p, x, cfg, BASE_RULES)  # no mesh -> reference
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
-        with set_mesh(mesh):
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AUTO, AUTO))
+        with jax.set_mesh(mesh):
             ep, aux_ep = jax.jit(lambda p, x: moe_apply(p, x, cfg, BASE_RULES))(p, x)
 
         err = float(jnp.max(jnp.abs(ref - ep)))
@@ -59,6 +61,8 @@ def test_mini_dryrun_lowers_and_compiles():
     dry-run plumbing (shardings, donation, cost/memory analysis)."""
     out = _run("""
         import jax, jax.numpy as jnp
+        from jax.sharding import AxisType
+        AUTO = AxisType.Auto
         from repro.configs.base import ShapeConfig
         from repro.configs.registry import get_arch, rules_for
         from repro.launch.lowering import lower_step
@@ -67,7 +71,7 @@ def test_mini_dryrun_lowers_and_compiles():
         cfg = get_arch("internlm2-1.8b").reduced()
         shape = ShapeConfig("mini_train", 64, 8, "train")
         rules = rules_for(cfg, shape, mesh_model=4, mesh_data=2)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AUTO, AUTO))
         lowered = lower_step(cfg, shape, mesh, rules)
         compiled = lowered.compile()
         cost = compiled.cost_analysis()
@@ -84,12 +88,14 @@ def test_train_step_numerically_equal_on_mesh_vs_single():
     """SPMD execution on 8 simulated devices == single-device math."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import AxisType
+        AUTO = AxisType.Auto
         from repro.configs.base import ShapeConfig
         from repro.configs.registry import get_arch
         from repro.data.synthetic import SyntheticLM
         from repro.launch.steps import make_train_step
         from repro.models.model import model_spec
-        from repro.models.sharding import BASE_RULES, named_sharding, set_mesh
+        from repro.models.sharding import BASE_RULES, named_sharding
         from repro.models.spec import init_params, param_shardings
         from repro.optim import make_optimizer, cosine_schedule
         from jax.sharding import PartitionSpec as P
@@ -104,8 +110,8 @@ def test_train_step_numerically_equal_on_mesh_vs_single():
         p1, o1, m1 = jax.jit(fn)(params, opt.init(params), jnp.int32(0), batch)
         loss_single = float(m1["loss"])
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
-        with set_mesh(mesh):
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AUTO, AUTO))
+        with jax.set_mesh(mesh):
             spec = model_spec(cfg)
             p_sh = param_shardings(spec, BASE_RULES, mesh)
             params_m = jax.device_put(params, p_sh)

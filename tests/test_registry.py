@@ -108,13 +108,16 @@ def test_cost_and_compiler_params_are_plain_dicts():
     spec = registry.get("fastchar.pallas")
     cost = spec.cost_estimate(rows=2, d=8, a=16, b=16, a_tile=8)
     assert set(cost) == {"flops", "bytes_accessed", "transcendentals"}
-    params = spec.compiler_params(rows=2, d_block=4, a_tile=8, b=16)
+    params = spec.params_fn(rows=2, d_block=4, a_tile=8, b=16)
     assert params["dimension_semantics"] == ("parallel", "parallel")
     assert params["vmem_limit_bytes"] >= (4 << 20)
+    # the spec wraps the dict into the one CompilerParams the kernels pass
+    built = spec.compiler_params(rows=2, d_block=4, a_tile=8, b=16)
+    assert tuple(built.dimension_semantics) == ("parallel", "parallel")
+    assert built.vmem_limit_bytes == params["vmem_limit_bytes"]
     gemv = registry.get("fastapp.pallas")
-    assert gemv.compiler_params(m=8, k_tile=16, n=8, a=16)[
-        "dimension_semantics"
-    ] == ("parallel", "arbitrary")
+    assert tuple(gemv.compiler_params(m=8, k_tile=128, n=8, a=16, rows=2)
+                 .dimension_semantics) == ("parallel", "arbitrary")
 
 
 # ---------------------------------------------------------------------------
@@ -148,10 +151,10 @@ def test_every_tile_candidate_matches_oracle(name):
 
 
 def test_entry_gemv_admits_12bit_where_table_kernel_cannot():
-    """The table-free GEMV's VMEM constraint (per-row planes, no (A, B)
-    table) admits 12-bit operands; the table kernel's resident 67 MB table
-    excludes every candidate at that width."""
-    shape = dict(n_bits=12, d=4, m=8, k=64, n=8)
+    """The table-free GEMV synthesizes its per-row planes, so its constraint
+    admits 12-bit operands; the table kernel's planes come from the RowTables
+    constant (1 GiB at that width), which excludes every candidate."""
+    shape = dict(n_bits=12, d=4, m=8, k=128, n=8)
     table = registry.get("fastapp.pallas")
     entry = registry.get("fastapp.entry_pallas")
     assert not table.candidates(table.bucket(**shape))
@@ -168,3 +171,15 @@ def test_moo_2d_friendly_default_layout():
     assert spec.default_tiles(spec.bucket(p=16, n_obj=2)) == {
         "tile": 16, "j_tile": 16,
     }
+
+
+def test_vmem_limits_count_lane_and_sublane_padding():
+    """A (P, 1) column occupies whole 128-lane tiles and a (1, P) row whole
+    8-sublane tiles; the dominance kernel's limit must cover them."""
+    assert registry._vmem_bytes(256, 1) == 4 * 256 * 128
+    assert registry._vmem_bytes(1, 256) == 4 * 8 * 256
+    assert registry._vmem_bytes(3, 5, 130) == 4 * 3 * 8 * 256
+    spec = registry.get("fastmoo.pallas")
+    limit = spec.params_fn(tile=64, j_tile=128, n_obj=2)["vmem_limit_bytes"]
+    padded_cols = 3 * registry._vmem_bytes(64, 1)
+    assert limit >= 2 * padded_cols + 10 * registry._vmem_bytes(64, 128)

@@ -1,0 +1,7 @@
+"""Share of the DSE window in which no operation ran on the device."""
+
+from counts import idle_share
+
+
+def read(ctx):
+    return idle_share(ctx)

@@ -135,8 +135,9 @@ def axo_linear(
     lead = x.shape[:-1]
     k = x.shape[-1]
     n = w.shape[1]
-    xq, sx = quantize_tensor(x.reshape(-1, k), op.n_bits)
-    wq, sw = quantize_tensor(w, op.n_bits)
+    with jax.named_scope("axo.quantize"):
+        xq, sx = quantize_tensor(x.reshape(-1, k), op.n_bits)
+        wq, sw = quantize_tensor(w, op.n_bits)
     f = jnp.asarray(op.f_table)
     g = jnp.asarray(op.g_table)
     sv = jnp.asarray(op.signed_vals, jnp.float32)
@@ -146,13 +147,14 @@ def axo_linear(
     # trace-time resolution count: one per (re)trace per call site, the
     # serving-path analogue of the registry dispatch counters
     obs.of(ctx).count(f"dispatch.axo_linear.{impl}")
-    if impl == "pallas":
-        tiles = tiles_for(ctx, "axo_matmul.pallas",
-                          m=xq.shape[0], k=k, n=n, rank=op.rank)
-        y = ops.axo_matmul(xq, wq, f, g, sv, **tiles)
-    else:
-        y = kref.ref_axo_matmul_lowrank(xq, wq, f, g, sv)
-    return (y * (sx * sw)).reshape(*lead, n).astype(x.dtype)
+    with jax.named_scope("axo.matmul"):
+        if impl == "pallas":
+            tiles = tiles_for(ctx, "axo_matmul.pallas",
+                              m=xq.shape[0], k=k, n=n, rank=op.rank)
+            y = ops.axo_matmul(xq, wq, f, g, sv, **tiles)
+        else:
+            y = kref.ref_axo_matmul_lowrank(xq, wq, f, g, sv)
+        return (y * (sx * sw)).reshape(*lead, n).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -206,23 +208,26 @@ class AxODeployment:
         k = x.shape[-1]
         bv = entry["bv"]
         n = bv.shape[-1]
-        xq, sx = quantize_tensor(
-            x.reshape(-1, k).astype(jnp.float32), self.op.n_bits
-        )
-        av = self.signed_vals[xq]                       # (M, K)
-        fa = jnp.moveaxis(self.f_table[xq], -1, 0)      # (R, M, K)
-        obs.of(self.ctx).count(f"dispatch.axo_apply.{self.impl}")
-        if self.impl == "pallas":
-            tiles = tiles_for(self.ctx, "axo_matmul.pallas",
-                              m=av.shape[0], k=k, n=n, rank=self.op.rank)
-            y = axo_matmul_pallas(
-                av, bv, fa, entry["gb"],
-                interpret=not ops.on_tpu(), **tiles,
+        with jax.named_scope("axo.quantize"):
+            xq, sx = quantize_tensor(
+                x.reshape(-1, k).astype(jnp.float32), self.op.n_bits
             )
-        else:
-            y = av @ bv + jnp.einsum("rmk,rkn->mn", fa, entry["gb"])
-        y = y * (sx * entry["scale"])
-        return y.reshape(*lead, n).astype(x.dtype)
+        with jax.named_scope("axo.gather"):
+            av = self.signed_vals[xq]                       # (M, K)
+            fa = jnp.moveaxis(self.f_table[xq], -1, 0)      # (R, M, K)
+        obs.of(self.ctx).count(f"dispatch.axo_apply.{self.impl}")
+        with jax.named_scope("axo.matmul"):
+            if self.impl == "pallas":
+                tiles = tiles_for(self.ctx, "axo_matmul.pallas",
+                                  m=av.shape[0], k=k, n=n, rank=self.op.rank)
+                y = axo_matmul_pallas(
+                    av, bv, fa, entry["gb"],
+                    interpret=not ops.on_tpu(), **tiles,
+                )
+            else:
+                y = av @ bv + jnp.einsum("rmk,rkn->mn", fa, entry["gb"])
+            y = y * (sx * entry["scale"])
+            return y.reshape(*lead, n).astype(x.dtype)
 
 
 def deploy_axo(
@@ -252,6 +257,10 @@ def deploy_axo(
 
     ``impl="pallas"`` runs the padded registry-tiled kernel; ``"xla"`` runs
     the jnp reference contraction (identical math, faster under CPU jit).
+
+    The build, until its arrays are on the device, is the ``axo.deploy``
+    span of ``ctx``'s telemetry, with the entry count and the deployment's
+    bytes as attributes.
     """
     unknown = set(layers) - set(AXO_LAYERS)
     if unknown:
@@ -259,6 +268,17 @@ def deploy_axo(
                          f"choose from {AXO_LAYERS}")
     if impl not in ("pallas", "xla"):
         raise ValueError(f"impl must be 'pallas' or 'xla', got {impl!r}")
+    tel = obs.of(ctx)
+    with tel.span("axo.deploy", impl=impl, layers=tuple(layers)) as span:
+        dep = jax.block_until_ready(
+            _build_deployment(params, op, cfg, tuple(layers), impl, ctx))
+    if tel.enabled:   # the disabled sink's span is shared
+        span.attrs.update(entries=dep.n_entries,
+                          bytes=sum(x.nbytes for x in jax.tree.leaves(dep)))
+    return dep
+
+
+def _build_deployment(params, op, cfg, layers, impl, ctx) -> AxODeployment:
     f_dev = jnp.asarray(op.f_table, jnp.float32)
     g_dev = jnp.asarray(op.g_table, jnp.float32)
     sv_dev = jnp.asarray(op.signed_vals, jnp.float32)
@@ -358,7 +378,7 @@ def deploy_axo(
         head = prep(w)
 
     return AxODeployment(
-        op=op, impl=impl, layers=tuple(layers),
+        op=op, impl=impl, layers=layers,
         f_table=f_dev, signed_vals=sv_dev,
         stages=stages, encoder=encoder, head=head,
         ctx=ctx, n_entries=count[0],

@@ -156,7 +156,6 @@ def compiled_cost(compiled) -> dict:
     Normalizes ``compiled.cost_analysis()`` (dict or single-element list
     depending on backend) and ``compiled.memory_analysis()`` into one flat
     record; missing analyses (some backends return None) read as zeros.
-    Shared by the roofline model and ``obs.profile``'s cost gauges.
     """
     cost = compiled.cost_analysis()
     if isinstance(cost, list):           # some backends return [dict]

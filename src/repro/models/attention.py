@@ -308,53 +308,59 @@ def attn_apply(
     cached weight factors (attention *math* -- scores/softmax -- stays exact;
     AxO replaces multiplier arrays, i.e. the matmuls).
     """
-    if axo is not None and "wq" in axo[1]:
-        dep, ent = axo
-        b_, s_ = x.shape[:2]
-        h_, hd_ = p["wq"].shape[1], p["wq"].shape[2]
-        g_ = p["wk"].shape[1]
-        q = dep.apply(x, ent["wq"]).reshape(b_, s_, h_, hd_)
-        k = dep.apply(x, ent["wk"]).reshape(b_, s_, g_, hd_)
-        v = dep.apply(x, ent["wv"]).reshape(b_, s_, g_, hd_)
-    else:
-        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-        k = jnp.einsum("bsd,dgk->bsgk", x, p["wk"])
-        v = jnp.einsum("bsd,dgk->bsgk", x, p["wv"])
-    q = constrain(q, rules, "batch", "seq", "heads", "head_dim")
-    k = constrain(k, rules, "batch", "seq", "kv_heads", "head_dim")
+    with jax.named_scope("attn.proj"):
+        if axo is not None and "wq" in axo[1]:
+            dep, ent = axo
+            b_, s_ = x.shape[:2]
+            h_, hd_ = p["wq"].shape[1], p["wq"].shape[2]
+            g_ = p["wk"].shape[1]
+            q = dep.apply(x, ent["wq"]).reshape(b_, s_, h_, hd_)
+            k = dep.apply(x, ent["wk"]).reshape(b_, s_, g_, hd_)
+            v = dep.apply(x, ent["wv"]).reshape(b_, s_, g_, hd_)
+        else:
+            q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+            k = jnp.einsum("bsd,dgk->bsgk", x, p["wk"])
+            v = jnp.einsum("bsd,dgk->bsgk", x, p["wv"])
+        q = constrain(q, rules, "batch", "seq", "heads", "head_dim")
+        k = constrain(k, rules, "batch", "seq", "kv_heads", "head_dim")
 
-    if use_rope:
-        cos, sin = rope_cos_sin(positions, cfg.resolved_head_dim, cfg.rope_theta)
-        q = rope_rotate(q, cos, sin)
-        k = rope_rotate(k, cos, sin)
+    with jax.named_scope("attn.core"):
+        if use_rope:
+            cos, sin = rope_cos_sin(positions, cfg.resolved_head_dim, cfg.rope_theta)
+            q = rope_rotate(q, cos, sin)
+            k = rope_rotate(k, cos, sin)
 
     new_cache = None
     if cache is not None:
-        ck = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k.astype(cache["k"].dtype), cache_index, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v.astype(cache["v"].dtype), cache_index, axis=1)
+        with jax.named_scope("attn.kv_update"):
+            ck = jax.lax.dynamic_update_slice_in_dim(
+                cache["k"], k.astype(cache["k"].dtype), cache_index, axis=1)
+            cv = jax.lax.dynamic_update_slice_in_dim(
+                cache["v"], v.astype(cache["v"].dtype), cache_index, axis=1)
         new_cache = {"k": ck, "v": cv}
         k, v = ck, cv
         kv_len = cache_index + x.shape[1]
     else:
         kv_len = x.shape[1]
 
-    if x.shape[1] <= 4:  # decode path
-        out = direct_attention(q, k, v, causal=causal, q_positions=positions, kv_len=kv_len)
-    else:
-        out = chunked_attention(
-            q, k, v, causal=causal, q_positions=positions, kv_len=kv_len,
-            q_chunk=cfg.attn_q_chunk, kv_chunk=cfg.attn_kv_chunk,
-            unroll=cfg.unroll_loops,
-            q_start=q_start if cfg.causal_block_skip else None,
-        )
-    if axo is not None and "wo" in axo[1]:
-        dep, ent = axo
-        out = dep.apply(out.reshape(*out.shape[:2], -1), ent["wo"])
-    else:
-        out = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
-    return constrain(out, rules, "batch", "seq", "embed"), new_cache
+    with jax.named_scope("attn.core"):
+        if x.shape[1] <= 4:  # decode path
+            out = direct_attention(q, k, v, causal=causal, q_positions=positions,
+                                   kv_len=kv_len)
+        else:
+            out = chunked_attention(
+                q, k, v, causal=causal, q_positions=positions, kv_len=kv_len,
+                q_chunk=cfg.attn_q_chunk, kv_chunk=cfg.attn_kv_chunk,
+                unroll=cfg.unroll_loops,
+                q_start=q_start if cfg.causal_block_skip else None,
+            )
+    with jax.named_scope("attn.proj"):
+        if axo is not None and "wo" in axo[1]:
+            dep, ent = axo
+            out = dep.apply(out.reshape(*out.shape[:2], -1), ent["wo"])
+        else:
+            out = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+        return constrain(out, rules, "batch", "seq", "embed"), new_cache
 
 
 # ---------------------------------------------------------------------------

@@ -82,11 +82,12 @@ def mlp_apply(p: dict, x: jnp.ndarray, cfg: ModelConfig, axo=None) -> jnp.ndarra
             return axo[0].apply(v, ent[name])
         return v @ p[name]
 
-    if cfg.act == "swiglu":
-        h = jax.nn.silu(lin("w_gate", x)) * lin("w_up", x)
-    else:
-        h = jax.nn.gelu(lin("w_up", x))
-    return lin("w_down", h)
+    with jax.named_scope("mlp"):
+        if cfg.act == "swiglu":
+            h = jax.nn.silu(lin("w_gate", x)) * lin("w_up", x)
+        else:
+            h = jax.nn.gelu(lin("w_up", x))
+        return lin("w_down", h)
 
 
 def embed_spec(cfg: ModelConfig) -> dict:

@@ -352,19 +352,23 @@ def _run_stage(
     body = jax.checkpoint(block) if (cfg.remat and ctx["mode"] == "train") else block
     carry0 = (x, jnp.zeros((), jnp.float32))
     xs = (stage_params, cache if cache else {}, axo_stage if axo_stage else {})
-    if cfg.unroll_loops:
-        # Cost-probe mode: Python loop so cost_analysis counts every repeat.
-        carry = carry0
-        ys = []
-        for r in range(stage.repeats):
-            carry, y = body(carry, jax.tree.map(lambda t: t[r], xs))
-            ys.append(y)
-        (x, aux) = carry
-        new_cache = (
-            jax.tree.map(lambda *t: jnp.stack(t), *ys) if ys and ys[0] else {}
-        )
-    else:
-        (x, aux), new_cache = jax.lax.scan(body, carry0, xs)
+    # the scan's own work (each layer's slice of the stacked weights, cache
+    # and AxO tables, the new cache's stacking) and what no sublayer scope
+    # names (norms, residual adds) reads as "layers" in a device trace
+    with jax.named_scope("layers"):
+        if cfg.unroll_loops:
+            # Cost-probe mode: Python loop so cost_analysis counts every repeat.
+            carry = carry0
+            ys = []
+            for r in range(stage.repeats):
+                carry, y = body(carry, jax.tree.map(lambda t: t[r], xs))
+                ys.append(y)
+            (x, aux) = carry
+            new_cache = (
+                jax.tree.map(lambda *t: jnp.stack(t), *ys) if ys and ys[0] else {}
+            )
+        else:
+            (x, aux), new_cache = jax.lax.scan(body, carry0, xs)
     return x, aux, (new_cache if new_cache else None)
 
 
@@ -461,13 +465,14 @@ def forward(
 
 def _unembed(params: dict, cfg: ModelConfig, rules: ShardingRules, x: jnp.ndarray,
              axo=None):
-    if axo is not None and axo.head is not None:
-        logits = axo.apply(x, axo.head)
-    elif cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"]["tok"])
-    else:
-        logits = x @ params["embed"]["unembed"]
-    return constrain(logits, rules, "batch", "res_seq", "vocab")
+    with jax.named_scope("head"):
+        if axo is not None and axo.head is not None:
+            logits = axo.apply(x, axo.head)
+        elif cfg.tie_embeddings:
+            logits = jnp.einsum("bsd,vd->bsv", x, params["embed"]["tok"])
+        else:
+            logits = x @ params["embed"]["unembed"]
+        return constrain(logits, rules, "batch", "res_seq", "vocab")
 
 
 def logits_fn(params, cfg, rules, x, axo=None):

@@ -3,9 +3,8 @@
 Collection: spans + counters/gauges/histograms (:mod:`.telemetry`),
 JSONL/Chrome-trace export (:mod:`.export`), on-device io_callback metric
 taps (:mod:`.device`).  Analysis + exposure: bench-history regression
-sentinel (:mod:`.regress`), compiled-cost profiling against the registry's
-analytical formulas (:mod:`.profile`), and Prometheus ``/metrics`` +
-``/healthz`` endpoints (:mod:`.prom`).  Stdlib-only at import time; JAX is
+sentinel (:mod:`.regress`) and Prometheus ``/metrics`` + ``/healthz``
+endpoints (:mod:`.prom`).  Stdlib-only at import time; JAX is
 touched lazily.
 """
 
@@ -31,8 +30,6 @@ from .device import flush, make_tap, null_tap
 _LAZY = {
     "MetricsServer": "prom", "health_payload": "prom",
     "render_prometheus": "prom",
-    "ProfileRecord": "profile", "check_estimate": "profile",
-    "profile_fn": "profile", "profile_registry": "profile",
     "append_history": "regress", "compare": "regress",
     "latest_report": "regress", "load_report": "regress",
 }
@@ -68,10 +65,6 @@ __all__ = [
     "MetricsServer",
     "health_payload",
     "render_prometheus",
-    "ProfileRecord",
-    "check_estimate",
-    "profile_fn",
-    "profile_registry",
     "append_history",
     "compare",
     "latest_report",

@@ -22,8 +22,12 @@ Design rules:
     are ``pass``, and device taps insert *nothing* into traced programs, so
     the off path is the pre-telemetry program bit for bit.
   * **No JAX here.**  This module is stdlib-only (numpy accepted at call
-    sites); the optional ``jax.profiler.TraceAnnotation`` passthrough and the
-    device taps import JAX lazily, so numpy-only processes stay JAX-free.
+    sites); the device taps import JAX lazily, and a span enters a
+    ``jax.profiler.TraceAnnotation`` only when JAX is already imported, so
+    numpy-only processes stay JAX-free.  In a JAX process every span is on
+    the profiler's clock: it appears on the host plane of a
+    ``jax.profiler`` trace, beside the device's operations (a TraceMe that
+    costs next to nothing while no profiler runs).
 
 Spans are thread- and contextvar-safe: the open-span stack lives in a
 ``contextvars.ContextVar``, so concurrent threads (or async tasks) nest
@@ -37,6 +41,7 @@ from __future__ import annotations
 import contextvars
 import functools
 import itertools
+import sys
 import threading
 import time
 from collections import deque
@@ -112,10 +117,9 @@ class _SpanCM:
             self._span.parent_id = stack[-1].span_id
         self._token = _SPAN_STACK.set(stack + (self._span,))
         self._span.t0 = time.perf_counter()
-        if self._tel.annotate:
-            self._annot = _trace_annotation(self._span.name)
-            if self._annot is not None:
-                self._annot.__enter__()
+        self._annot = _trace_annotation(self._span.name)
+        if self._annot is not None:
+            self._annot.__enter__()
         return self._span
 
     def __exit__(self, *exc) -> None:
@@ -127,14 +131,13 @@ class _SpanCM:
 
 
 def _trace_annotation(name: str):
-    """A ``jax.profiler.TraceAnnotation`` when JAX is importable, else None --
-    spans then line up with XLA activity in a jax.profiler trace."""
-    try:
-        import jax.profiler
-
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
+    """A ``jax.profiler.TraceAnnotation`` when JAX is already imported, else
+    None: spans then line up with XLA activity in a jax.profiler trace."""
+    if "jax" not in sys.modules:
         return None
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
 
 
 class Telemetry:
@@ -155,12 +158,10 @@ class Telemetry:
         name: str = "telemetry",
         parent: "Telemetry | None" = None,
         device_taps: bool = False,
-        annotate: bool = False,
     ) -> None:
         self.name = name
         self.parent = parent
         self.device_taps = bool(device_taps)
-        self.annotate = bool(annotate)
         self._lock = threading.Lock()
         self._ids = itertools.count(1)
         self.spans: deque = deque(maxlen=_MAX_SPANS)

@@ -112,6 +112,21 @@ def test_deploy_entry_counts_and_validation():
         deploy_axo(params, op, cfg, impl="cuda")
 
 
+def test_deploy_is_one_span_with_its_entries_and_bytes():
+    from repro.core.engine import ExecutionContext
+    from repro.obs import telemetry as tm
+
+    cfg, params = _granite()
+    tel = tm.Telemetry("deploy")
+    dep = deploy_axo(params, _mild_op(rank=2), cfg, layers=("attn",),
+                     impl="xla", ctx=ExecutionContext(telemetry=tel))
+    (span,) = [s for s in tel.spans if s.name == "axo.deploy"]
+    assert span.duration_s > 0
+    assert span.attrs["entries"] == dep.n_entries == 4
+    assert span.attrs["bytes"] == sum(x.nbytes for x in jax.tree.leaves(dep))
+    assert span.attrs["impl"] == "xla" and span.attrs["layers"] == ("attn",)
+
+
 def test_deployment_entries_cache_weight_factors():
     """Entries carry pre-gathered signed values and G_r(W) with the stacked
     repeats axis; head is unstacked (d, vocab)."""

@@ -4,6 +4,9 @@ Four contracts:
 
   * **Spans** nest via a contextvar stack (thread-isolated), round-trip
     through JSONL, and export to Chrome-trace JSON with parent containment.
+    In a JAX process each span is also a ``TraceAnnotation``, so it shows
+    on the host plane of a ``jax.profiler`` trace; a process without JAX
+    never imports it.
   * **Device taps** are per-*dispatch* ``io_callback`` sinks: a tap inside a
     ``fori_loop`` fires N times per compiled-program execution (never once
     per trace), and a disabled (NULL) tap stages nothing -- the program is
@@ -17,6 +20,10 @@ Four contracts:
 """
 
 import json
+import os
+import pathlib
+import subprocess
+import sys
 import threading
 import time
 
@@ -283,6 +290,51 @@ def test_disabled_telemetry_overhead_guard():
             tel.gauge("g", 1.0)
     per_op = (time.perf_counter() - t0) / (4 * n)
     assert per_op < 5e-6, f"null telemetry op took {per_op * 1e6:.2f}us"
+
+
+# ---------------------------------------------------------------------------
+# Spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def test_span_appears_on_the_profilers_host_plane(tmp_path):
+    tel = tm.Telemetry("traced")
+    with jax.profiler.trace(str(tmp_path)):
+        with tel.span("obs.test.traced_span"):
+            jnp.arange(8.0).sum().block_until_ready()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    names = {ev.name for plane in data.planes if plane.name == "/host:CPU"
+             for line in plane.lines for ev in line.events}
+    assert "obs.test.traced_span" in names
+    assert [s.name for s in tel.spans] == ["obs.test.traced_span"]
+
+
+def test_span_in_a_process_without_jax_stays_jax_free():
+    code = ("import sys\n"
+            "from repro.obs import telemetry as tm\n"
+            "tel = tm.Telemetry()\n"
+            "with tel.span('numpy.only'):\n"
+            "    pass\n"
+            "print(len(tel.spans), 'jax' in sys.modules)\n")
+    src = str(pathlib.Path(tm.__file__).resolve().parents[2])
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120,
+                         env=dict(os.environ, PYTHONPATH=src), check=True)
+    assert out.stdout.split() == ["1", "False"]
+
+
+def test_enabled_span_without_a_profiler_costs_microseconds():
+    """A span in a JAX process enters a TraceAnnotation, which records
+    nothing while no profiler runs: a span stays in the microseconds."""
+    tel = tm.Telemetry("cost")
+    n = 20_000
+    t0 = time.perf_counter()
+    for i in range(n):
+        with tel.span("x"):
+            pass
+    per_span = (time.perf_counter() - t0) / n
+    assert per_span < 50e-6, f"a span took {per_span * 1e6:.2f}us"
 
 
 # ---------------------------------------------------------------------------

@@ -11,9 +11,6 @@
     ``_total``, histograms as summaries with quantile labels, names are
     sanitized to the Prometheus charset; ``/metrics`` + ``/healthz`` round-
     trip over real HTTP against the live telemetry.
-  * **Compiled-cost profiling** (``repro.obs.profile``): ``profile_fn``
-    captures XLA ``cost_analysis()`` numbers as gauges under jit, and
-    ``check_estimate`` flags >2x estimate-vs-measured divergence both ways.
 """
 
 import json
@@ -482,82 +479,3 @@ def test_alert_rules_reference_live_exposition_names():
     assert referenced  # the rules do gate repro_* metrics
     missing = referenced - exposed
     assert not missing, f"alert rules reference unexposed metrics: {missing}"
-
-
-# ---------------------------------------------------------------------------
-# Compiled-cost profiling
-# ---------------------------------------------------------------------------
-
-
-def test_profile_fn_captures_cost_gauges_under_jit():
-    import jax
-    import jax.numpy as jnp
-
-    from repro.obs.profile import profile_fn
-
-    tel = tm.Telemetry("prof")
-
-    def matmul(a, b):
-        return a @ b
-
-    a = jnp.ones((64, 64), jnp.float32)
-    b = jnp.ones((64, 64), jnp.float32)
-    rec = profile_fn(matmul, a, b, name="mm", tel=tel)
-    # a 64^3 matmul is 2*64^3 flops by XLA's own accounting
-    assert rec.cost["flops"] == pytest.approx(2 * 64**3)
-    assert rec.cost["bytes_accessed"] > 0
-    assert rec.cost["peak_bytes"] >= rec.cost["argument_bytes"] > 0
-    assert tel.gauges["profile.mm.flops"] == rec.cost["flops"]
-    assert tel.counter("profile.compiles") == 1
-    assert tel.series["profile"][0]["name"] == "mm"
-    # an already-jitted callable goes straight to lower()
-    rec2 = profile_fn(jax.jit(matmul), a, b, name="mm2", tel=tel)
-    assert rec2.cost["flops"] == rec.cost["flops"]
-
-
-def test_check_estimate_flags_2x_divergence_both_ways():
-    from repro.obs.profile import ProfileRecord, check_estimate
-
-    tel = tm.Telemetry("prof")
-    rec = ProfileRecord("k", {"flops": 1000.0, "bytes_accessed": 500.0})
-    # within 2x both ways: no flags
-    ok = check_estimate(
-        ProfileRecord("k", dict(rec.cost)),
-        {"flops": 600.0, "bytes_accessed": 900.0}, tel=tel,
-    )
-    assert ok.flagged == ()
-    # >2x under-estimate and >2x over-estimate both flag
-    bad = check_estimate(
-        ProfileRecord("k", dict(rec.cost)),
-        {"flops": 400.0, "bytes_accessed": 1100.0}, tel=tel,
-    )
-    assert set(bad.flagged) == {"flops", "bytes_accessed"}
-    assert bad.divergence["flops"] == pytest.approx(2.5)
-    assert tel.counter("profile.estimate_divergence") == 2
-    assert tel.gauges["profile.k.divergence.flops"] == pytest.approx(2.5)
-    # zero estimate with nonzero measurement flags as inf
-    z = check_estimate(
-        ProfileRecord("k", dict(rec.cost)), {"flops": 0.0}, tel=tel
-    )
-    assert z.divergence["flops"] == float("inf") and "flops" in z.flagged
-
-
-def test_profile_registry_covers_all_three_pallas_engines():
-    from repro.obs.profile import profile_registry
-
-    tel = tm.Telemetry("prof")
-    with tm.use(tel):
-        records = profile_registry()
-    names = {r.name for r in records}
-    assert names == {"fastchar.pallas", "fastapp.pallas", "fastmoo.pallas"}
-    for r in records:
-        # XLA produced real numbers for every engine...
-        assert r.cost["flops"] > 0, r.name
-        assert r.cost["bytes_accessed"] > 0, r.name
-        assert r.cost["peak_bytes"] > 0, r.name
-        # ...the registered formula produced an estimate...
-        assert r.estimate is not None and r.estimate["flops"] > 0, r.name
-        # ...and the divergence check ran on both checked stats
-        assert set(r.divergence) == {"flops", "bytes_accessed"}, r.name
-        assert tel.gauges[f"profile.{r.name}.flops"] == r.cost["flops"]
-    assert tel.counter("profile.compiles") == 3

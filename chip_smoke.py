@@ -202,8 +202,7 @@ def projection_parity(seed: int) -> float:
     entry = {"bv": sv[wq],
              "gb": jnp.moveaxis(jnp.asarray(op.g_table)[wq], -1, 0),
              "scale": sw}
-    dep = AxODeployment(op=op, impl="pallas", layers=("attn",),
-                        f_table=jnp.asarray(op.f_table), signed_vals=sv)
+    dep = AxODeployment(op=op, impl="pallas", layers=("attn",))
     y_kernel = np.asarray(dep.apply(x, entry))
     with jax.default_matmul_precision("highest"):
         y_xla = np.asarray(dataclasses.replace(dep, impl="xla").apply(x, entry))

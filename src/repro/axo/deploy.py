@@ -13,11 +13,16 @@ serving path:
      and dequantization.
   3. ``deploy_axo``: walk a model's param tree and build an
      :class:`AxODeployment` -- per-layer **cached** weight codes/scales and
-     pre-gathered ``G_r(W)`` factors for every attention q/k/v/o, MLP and MoE
+     pre-computed ``G_r(W)`` factors for every attention q/k/v/o, MLP and MoE
      expert projection (plus the LM head), so decode steps never requantize or
-     re-gather weights per token.  The deployment is a pytree and threads
+     look weights up again per token.  The deployment is a pytree and threads
      through ``models.model.forward(axo=...)`` and the ``launch.steps`` steps
      as a jit argument.
+
+Both the deployment and its decode steps turn codes into operand values and
+factors with :func:`code_lookup`: arithmetic decoding and a select over the
+operator's small factor table where the operator allows it, since a TPU
+gather of scalars runs about one element at a time.
 
 The bit-exact table path (exhaustive gather) stays available for validation;
 production uses the rank-R MXU path (DESIGN.md §3.2).
@@ -50,6 +55,8 @@ __all__ = [
     "AxODeployment",
     "AXO_LAYERS",
     "quantize_tensor",
+    "code_lookup",
+    "lookup_path",
     "axo_linear",
     "deploy_axo",
 ]
@@ -117,6 +124,62 @@ def quantize_tensor(x: jnp.ndarray, n_bits: int = 8) -> tuple[jnp.ndarray, jnp.n
     return q & ((1 << n_bits) - 1), scale
 
 
+#: widest operand whose factor table is selected over: a select costs
+#: 2^n - 1 elementwise ``where``s a code, a gather one serialized read
+SELECT_MAX_BITS = 8
+
+
+def _decoding(op: AxOOperator) -> str | None:
+    """How ``op.signed_vals`` decodes a code: "signed" (two's complement),
+    "unsigned" (the code itself), or None when it is neither, or the
+    operator is too wide to select over."""
+    if op.n_bits > SELECT_MAX_BITS:
+        return None
+    n = 1 << op.n_bits
+    codes = np.arange(n)
+    if np.array_equal(op.signed_vals, np.where(codes >= n // 2, codes - n, codes)):
+        return "signed"
+    if np.array_equal(op.signed_vals, codes):
+        return "unsigned"
+    return None
+
+
+def lookup_path(op: AxOOperator) -> str:
+    """The path :func:`code_lookup` takes for ``op``: "select" or "gather"."""
+    return "gather" if _decoding(op) is None else "select"
+
+
+def code_lookup(
+    op: AxOOperator,
+    codes: jnp.ndarray,          # (...) integer codes in [0, 2^n)
+    side: str,                   # "f": left (activation) factors, "g": right
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Operand values (...) float32 and factors (R, ...) of ``codes``.
+
+    Bit-identical to ``signed_vals[codes]`` and ``moveaxis(table[codes],
+    -1, 0)`` for ``op``'s float32 tables, which it computes as they read
+    where :func:`lookup_path` says "gather".  Otherwise the value is decoded
+    arithmetically, and each factor is picked by a depth-n tree of
+    ``where`` on the code's bits over the table's 2^n rows: exact, one
+    fusion, no array larger than the output.  The tables are the
+    operator's static numpy arrays, so they enter the program as constants.
+    """
+    table = np.asarray(op.f_table if side == "f" else op.g_table, np.float32)
+    decoding = _decoding(op)
+    if decoding is None:
+        sv = jnp.asarray(op.signed_vals, jnp.float32)
+        return sv[codes], jnp.moveaxis(jnp.asarray(table)[codes], -1, 0)
+    n = 1 << op.n_bits
+    vals = codes if decoding == "unsigned" else jnp.where(
+        codes >= n // 2, codes - n, codes)
+    nodes = list(table.reshape(n, table.shape[1], *(1,) * codes.ndim))
+    for b in range(op.n_bits):
+        bit = ((codes >> b) & 1)[None] == 1
+        nodes = [jnp.where(bit, hi, lo)
+                 for lo, hi in zip(nodes[0::2], nodes[1::2])]
+    return vals.astype(jnp.float32), nodes[0]
+
+
 def axo_linear(
     x: jnp.ndarray,              # (..., K) float activations
     w: jnp.ndarray,              # (K, N) float weights
@@ -167,7 +230,7 @@ AXO_LAYERS = ("attn", "mlp", "moe", "head")
 
 @functools.partial(
     jax.tree_util.register_dataclass,
-    data_fields=["f_table", "signed_vals", "stages", "encoder", "head"],
+    data_fields=["stages", "encoder", "head"],
     meta_fields=["op", "impl", "layers", "ctx", "n_entries"],
 )
 @dataclass(frozen=True)
@@ -175,16 +238,18 @@ class AxODeployment:
     """DSE-selected operator deployed into every linear layer of a model.
 
     Weights are quantized ONCE at deploy time: each entry caches the weight's
-    signed value matrix ``bv = signed_vals[Wq]`` (K, N), the pre-gathered
-    right factors ``gb = G_r(Wq)`` (R, K, N) and the weight scale -- decode
-    steps only quantize the (tiny) activation and gather its left factors.
+    signed value matrix ``bv = signed_vals[Wq]`` (K, N), its right factors
+    ``gb = G_r(Wq)`` (R, K, N) and the weight scale -- decode steps only
+    quantize the (tiny) activation and look up its values and left factors
+    (:func:`code_lookup`).
     Entries for stacked layers carry a leading ``repeats`` axis so they ride
     through ``jax.lax.scan`` next to the params.
 
-    A pytree whose leaves are the tables and entries: pass it to a jitted
-    step as an argument.  Closed over, it would be embedded in the program
-    as constants -- 3.4 GB for granite-3-2b's attention projections at
-    rank 1, enough for lowering to exhaust a 40 GiB host.
+    A pytree whose leaves are the entries (the operator and its tables are
+    static metadata): pass it to a jitted step as an argument.  Closed
+    over, it would be embedded in the program as constants -- 3.4 GB for
+    granite-3-2b's attention projections at rank 1, enough for lowering to
+    exhaust a 40 GiB host.
 
     ``stages[str(si)][str(li)]`` mirrors ``params["stages"]`` with per-layer
     ``{"mixer": ..., "mlp": ...}`` entry dicts; ``encoder`` mirrors the
@@ -194,8 +259,6 @@ class AxODeployment:
     op: AxOOperator
     impl: str                            # "pallas" | "xla"
     layers: tuple
-    f_table: jnp.ndarray                 # (2^n, R) f32, device-resident
-    signed_vals: jnp.ndarray             # (2^n,) f32
     stages: dict = field(default_factory=dict)
     encoder: dict | None = None
     head: dict | None = None
@@ -213,9 +276,11 @@ class AxODeployment:
                 x.reshape(-1, k).astype(jnp.float32), self.op.n_bits
             )
         with jax.named_scope("axo.gather"):
-            av = self.signed_vals[xq]                       # (M, K)
-            fa = jnp.moveaxis(self.f_table[xq], -1, 0)      # (R, M, K)
-        obs.of(self.ctx).count(f"dispatch.axo_apply.{self.impl}")
+            # (M, K) values, (R, M, K) factors
+            av, fa = code_lookup(self.op, xq, "f")
+        tel = obs.of(self.ctx)
+        tel.count(f"dispatch.axo_apply.{self.impl}")
+        tel.count(f"dispatch.axo_lookup.{lookup_path(self.op)}")
         with jax.named_scope("axo.matmul"):
             if self.impl == "pallas":
                 tiles = tiles_for(self.ctx, "axo_matmul.pallas",
@@ -278,21 +343,20 @@ def deploy_axo(
     return dep
 
 
+#: one program for the lookup: eagerly, the select's tree would be 2^n - 1
+#: dispatches, each writing a whole weight-sized array
+_code_lookup_jit = jax.jit(code_lookup, static_argnums=(0, 2))
+
+
 def _build_deployment(params, op, cfg, layers, impl, ctx) -> AxODeployment:
-    f_dev = jnp.asarray(op.f_table, jnp.float32)
-    g_dev = jnp.asarray(op.g_table, jnp.float32)
-    sv_dev = jnp.asarray(op.signed_vals, jnp.float32)
     count = [0]
 
     def prep(w2d):
         """(K, N) weight -> cached codes/values/factors entry."""
         wq, sw = quantize_tensor(jnp.asarray(w2d, jnp.float32), op.n_bits)
         count[0] += 1
-        return {
-            "bv": sv_dev[wq],                           # (K, N)
-            "gb": jnp.moveaxis(g_dev[wq], -1, 0),       # (R, K, N)
-            "scale": sw,
-        }
+        bv, gb = _code_lookup_jit(op, wq, "g")   # (K, N), (R, K, N)
+        return {"bv": bv, "gb": gb, "scale": sw}
 
     def prep_r(w, tail2=None):
         """Stacked (repeats, ...) weight -> entry with a leading repeats axis."""
@@ -379,7 +443,6 @@ def _build_deployment(params, op, cfg, layers, impl, ctx) -> AxODeployment:
 
     return AxODeployment(
         op=op, impl=impl, layers=layers,
-        f_table=f_dev, signed_vals=sv_dev,
         stages=stages, encoder=encoder, head=head,
         ctx=ctx, n_entries=count[0],
     )

@@ -2,6 +2,7 @@
 whole-model deployment entry structure, and generation fidelity of a
 fully-deployed reduced LM vs the exact serving path."""
 
+import dataclasses
 import functools
 
 import jax
@@ -94,6 +95,36 @@ def test_deployment_decode_shape_dispatches_pallas(monkeypatch):
     assert calls == [(4, cfg.d_model)]
 
 
+def _fallback_op(rank=2):
+    """The mild design with a sign-magnitude decoding: neither two's
+    complement nor the code itself, so its lookups stay gathers."""
+    op = _mild_op(rank)
+    codes = np.arange(1 << op.n_bits)
+    sv = np.where(codes >= 128, 128 - codes, codes).astype(np.int32)
+    return dataclasses.replace(op, signed_vals=sv)
+
+
+@pytest.mark.parametrize("path", ["select", "gather"])
+def test_apply_counts_its_lookup_path(path):
+    """Each trace of ``apply`` counts the lookup it engaged, beside the
+    kernel dispatch count."""
+    from repro.core.engine import ExecutionContext
+    from repro.obs import telemetry as tm
+
+    cfg, params = _granite()
+    op = _mild_op(rank=2) if path == "select" else _fallback_op(rank=2)
+    tel = tm.Telemetry("lookup")
+    dep = deploy_axo(params, op, cfg, layers=("head",), impl="xla",
+                     ctx=ExecutionContext(telemetry=tel))
+    assert deploy_mod.lookup_path(op) == path
+    x = jnp.asarray(RNG.standard_normal((4, cfg.d_model)), jnp.float32)
+    dep.apply(x, dep.head)
+    other = {"select": "gather", "gather": "select"}[path]
+    assert tel.counter(f"dispatch.axo_lookup.{path}") == 1
+    assert tel.counter(f"dispatch.axo_lookup.{other}") == 0
+    assert tel.counter("dispatch.axo_apply.xla") == 1
+
+
 # ---------------------------------------------------------------------------
 # Deployment structure + per-entry semantics
 # ---------------------------------------------------------------------------
@@ -150,7 +181,7 @@ def test_deployment_enters_steps_as_an_argument():
     cfg, params = _granite()
     dep = deploy_axo(params, _mild_op(rank=2), cfg, impl="xla")
     n_dep = len(jax.tree.leaves(dep))
-    assert n_dep == 2 + 3 * dep.n_entries   # tables + (bv, gb, scale) each
+    assert n_dep == 3 * dep.n_entries   # (bv, gb, scale) each; tables static
     toks = jnp.zeros((2, 8), jnp.int32)
     step = make_prefill_step(cfg, BASE_RULES, max_seq=8)
     closed = jax.make_jaxpr(step)(params, toks, axo=dep)

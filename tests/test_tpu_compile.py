@@ -88,12 +88,32 @@ def _axo(m, k=2048, n=2048, rank=1):
              ((rank, m, k), jnp.float32), ((rank, k, n), jnp.float32)])
 
 
+def _axo_apply(m, k=2048, n=2048):
+    # AxODeployment.apply at granite's decode shape: the activation's
+    # quantize and code lookups in XLA, then the kernel
+    from unittest import mock
+
+    from repro.axo.deploy import AxODeployment
+    from repro.kernels import ops
+    from repro.launch.serve import demo_operator
+
+    dep = AxODeployment(op=demo_operator(1), impl="pallas", layers=("attn",))
+
+    def apply(x, bv, gb, scale):
+        with mock.patch.object(ops, "on_tpu", lambda: True):
+            return dep.apply(x, {"bv": bv, "gb": gb, "scale": scale})
+
+    return (apply, [((m, k), jnp.float32), ((k, n), jnp.float32),
+                    ((1, k, n), jnp.float32), ((), jnp.float32)])
+
+
 CASES = {
     "fastchar.pallas-8bit-D1024": _fastchar,
     "fastapp.pallas-mnist-head": _fastapp,
     "fastmoo.pallas-P256": _fastmoo,
     "axo_matmul.pallas-decode-M8": functools.partial(_axo, 8),
     "axo_matmul.pallas-prefill-M512": functools.partial(_axo, 512),
+    "axo_deploy.apply-decode-M8": functools.partial(_axo_apply, 8),
 }
 
 

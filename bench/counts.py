@@ -37,21 +37,22 @@ def axo_matmul_work(m: int, k: int, n: int, rank: int, n_bits: int = 8):
     return ops, nbytes
 
 
-def dense_shapes(model: dict) -> dict:
-    """(K, N) of each linear projection of one dense GQA layer, by name."""
-    d, h, g = model["d_model"], model["n_heads"], model["kv_heads"]
-    hd, f = d // h, model["d_ff"]
-    return {"wq": (d, h * hd), "wk": (d, g * hd), "wv": (d, g * hd),
-            "wo": (h * hd, d), "w_gate": (d, f), "w_up": (d, f), "w_down": (f, d)}
+def projections(model: dict):
+    """(group, K, N) of every linear projection of every layer, in order,
+    from a model family's ``shapes``."""
+    for layer in model["layers"]:
+        for group, projs in layer.items():
+            for k, n in projs.values():
+                yield group, k, n
 
 
 def model_flops(model: dict, n_tokens: int, context: int) -> float:
     """Model FLOPs of ``n_tokens`` tokens that each attend over ``context``
     positions: 2 x matmul parameters (the tied head included) plus QK^T and
-    PV over the context, per token."""
-    params = model["n_layers"] * sum(k * n for k, n in dense_shapes(model).values())
+    PV over the context in each attention layer, per token."""
+    params = sum(k * n for _, k, n in projections(model))
     params += model["d_model"] * model["vocab"]
-    attn = 2 * 2 * model["n_layers"] * context * model["d_model"]
+    attn = 2 * 2 * model["attn_layers"] * context * model["d_model"]
     return n_tokens * (2 * params + attn)
 
 
@@ -60,10 +61,10 @@ def serve_flops(model: dict, batch: int, prompt_len: int, gen: int,
     """Model FLOPs of one served batch: a prefill of the prompt (its layers
     over every position, the head at the last) and gen - 1 decode steps."""
     p, d, v = prompt_len, model["d_model"], model["vocab"]
-    layer_params = sum(k * n for k, n in dense_shapes(model).values())
+    params = sum(k * n for _, k, n in projections(model))
     # prefill: every prompt position through every layer, causal context
-    pre = batch * p * 2 * model["n_layers"] * layer_params
-    pre += batch * 2 * 2 * model["n_layers"] * d * (p * (p + 1) // 2)
+    pre = batch * p * 2 * params
+    pre += batch * 2 * 2 * model["attn_layers"] * d * (p * (p + 1) // 2)
     pre += batch * head_positions * 2 * d * v
     dec = sum(model_flops(model, batch, p + i + 1) for i in range(gen - 1))
     return pre + dec
@@ -71,18 +72,11 @@ def serve_flops(model: dict, batch: int, prompt_len: int, gen: int,
 
 def axo_calls(model: dict, layers: tuple, batch: int, prompt_len: int,
               gen: int) -> list:
-    """(M, K, N) of every AxO matmul one served batch makes."""
-    names = []
-    if "attn" in layers:
-        names += ["wq", "wk", "wv", "wo"]
-    if "mlp" in layers:
-        names += ["w_gate", "w_up", "w_down"]
-    shapes = dense_shapes(model)
-    out = []
-    for m in [batch * prompt_len] + [batch] * (gen - 1):
-        for _ in range(model["n_layers"]):
-            out += [(m, *shapes[nm]) for nm in names]
-    return out
+    """(M, K, N) of every AxO matmul one served batch makes: each projection
+    of a group named in ``layers``, in every layer, at every step."""
+    shapes = [(k, n) for group, k, n in projections(model) if group in layers]
+    return [(m, k, n) for m in [batch * prompt_len] + [batch] * (gen - 1)
+            for k, n in shapes]
 
 
 def idle_share(ctx) -> float | None:

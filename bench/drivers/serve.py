@@ -1,9 +1,11 @@
 """Driver for serving traffic: closed-loop greedy batches through the jitted
 prefill and decode steps, with or without an AxO deployment.
 
-Set-up builds the program's model from the configuration file, makes every
-weight on the device from the seed in one jitted call (bfloat16, as served,
-with the Granite multipliers folded in: see ``served_model``), deploys the traffic's approximate operator (``deploy_axo``) if it names one,
+Set-up builds the program's model from the configuration file through its
+model family (``bench/models/<model_type>.py``), makes every weight on the
+device from the seed in one jitted call (bfloat16, as served, with any
+factors the family folds in), deploys the traffic's approximate operator
+(``deploy_axo``) if it names one,
 and serves one warm-up batch, so every program the window runs is compiled.
 The window serves batches back to back.  Each batch is ``batch`` requests
 of ``prompt_len`` token ids from the seed, decoded greedily for ``gen``
@@ -23,50 +25,30 @@ from __future__ import annotations
 
 import functools
 import time
+from pathlib import Path
 
 import numpy as np
 
 import generate
-from run import Check
+from run import Check, load_module
+
+MODELS = Path(__file__).resolve().parents[1] / "models"
+
+
+def family(config: dict):
+    """The model family ``bench/models/<model_type>.py`` of a configuration:
+    its ``served_model``, ``reference_weights`` and ``shapes``."""
+    path = MODELS / f"{config.get('model_type')}.py"
+    if not path.is_file():
+        raise ValueError(f"no model family for model_type "
+                         f"{config.get('model_type')!r}: looked for {path}")
+    return load_module(path)
 
 
 def served_model(config: dict):
     """The program's ModelConfig for a configuration file, and the factors
-    that fold the Granite multipliers into its weights.
-
-    The program's dense decoder has no Granite multiplier: it scales scores
-    by 1/sqrt(head_dim), embeds unscaled, adds each branch unscaled and does
-    not divide the logits.  Served on the residual stream divided by
-    ``residual_multiplier`` r, the published model is that decoder exactly:
-    the embedding times e/r, every branch then added unscaled, RMSNorm's eps
-    divided by r^2 (the norm sees the stream r times smaller), the final
-    norm's gain times r/(e * logits_scaling) (the tied head reads the
-    embedding e/r times larger), and the query weights times
-    attention_multiplier * sqrt(head_dim), a power of two here, so the
-    query projection's 8-bit codes are the published weights' own.
-    """
-    from repro.configs.base import ModelConfig, StageConfig
-
-    for key, want in (("hidden_act", "silu"), ("model_type", "granite")):
-        if config[key] != want:
-            raise ValueError(f"the program runs {key}={want!r}, the "
-                             f"configuration states {config[key]!r}")
-    d, h = config["hidden_size"], config["num_attention_heads"]
-    e, r = config["embedding_multiplier"], config["residual_multiplier"]
-    fold = {"tok": e / r,
-            "norm_f": r / (e * config["logits_scaling"]),
-            "wq": config["attention_multiplier"] * (d // h) ** 0.5}
-    cfg = ModelConfig(
-        name=config["name"], family="dense", d_model=d, n_heads=h,
-        kv_heads=config["num_key_value_heads"],
-        d_ff=config["intermediate_size"], vocab=config["vocab_size"],
-        stages=(StageConfig(repeats=config["num_hidden_layers"],
-                            layers=(("attn", "dense"),)),),
-        rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]) / r ** 2,
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-    )
-    return cfg, fold
+    folded into its weights where the program's model has no such knob."""
+    return family(config).served_model(config)
 
 
 def make_weights(cfg, std: float, seed: int, fold: dict | None = None):
@@ -97,23 +79,6 @@ def make_weights(cfg, std: float, seed: int, fold: dict | None = None):
         return jax.tree_util.tree_unflatten(treedef, out)
 
     return make(jax.random.key(generate.weight_seed(seed)))
-
-
-def reference_weights(params: dict, cfg) -> dict:
-    """The same arrays in the reference's layout (views, no copies)."""
-    st = params["stages"]["0"]["0"]
-    n, d = cfg.n_layers, cfg.d_model
-    mix, mlp = st["mixer"], st["mlp"]
-    return {
-        "embed": params["embed"]["tok"], "norm_f": params["norm_f"],
-        "layers": {
-            "norm1": st["norm1"], "norm2": st["norm2"],
-            "wq": mix["wq"].reshape(n, d, -1), "wk": mix["wk"].reshape(n, d, -1),
-            "wv": mix["wv"].reshape(n, d, -1), "wo": mix["wo"].reshape(n, -1, d),
-            "w_gate": mlp["w_gate"], "w_up": mlp["w_up"],
-            "w_down": mlp["w_down"],
-        },
-    }
 
 
 def deploy(params, cfg, axo: dict | None):
@@ -152,14 +117,16 @@ class Server:
     def batch(self, prompts: np.ndarray, gaps: list, keep: list | None = None):
         """Serve one batch; returns its tokens (B, gen) and appends the
         inter-token gaps (seconds) to ``gaps``.  ``keep`` collects the logits
-        each step produced (on the device, for the check)."""
+        each step produced, (B, 1, V) on the device, for the check: held as
+        they are, so that the batch whose logits are kept dispatches no more
+        work a step than any other."""
         jnp, run = self.jnp, self.run
         with run.span("bench.prefill"):
             logits, cache = self.prefill(self.params, jnp.asarray(prompts))
             nxt = self.argmax(logits)
             out = [np.asarray(nxt)]
         if keep is not None:
-            keep.append(logits[:, -1])
+            keep.append(logits)
         t_prev = time.perf_counter()
         for i in range(self.p, self.p + self.g - 1):
             with run.span("bench.decode"):
@@ -168,7 +135,7 @@ class Server:
                 nxt = self.argmax(logits)
                 out.append(np.asarray(nxt))
             if keep is not None:
-                keep.append(logits[:, -1])
+                keep.append(logits)
             t = time.perf_counter()
             gaps.append(t - t_prev)
             t_prev = t
@@ -205,6 +172,7 @@ def run(cell, run, reference, control: str | None = None) -> dict:
     import gc
 
     config, traffic = cell.config, cell.traffic
+    fam = family(config)
     std = float(config["initializer_range"])
     with run.span("bench.setup"):
         cfg, fold = served_model(config)
@@ -235,7 +203,7 @@ def run(cell, run, reference, control: str | None = None) -> dict:
     picked = generate.sample(len(served), int(traffic["check_batches"]),
                              run.seed, must=(0, len(served) - 1))
     # the weights as the configuration states them, drawn again from the seed
-    weights = reference_weights(make_weights(cfg, std, run.seed), cfg)
+    weights = fam.reference_weights(make_weights(cfg, std, run.seed), cfg)
     gaps_ref, err = [], None
     for b in picked:
         prompts = generate.prompts(traffic, cfg.vocab, run.seed, b)
@@ -245,7 +213,7 @@ def run(cell, run, reference, control: str | None = None) -> dict:
         if b == 0:
             import jax.numpy as jnp
 
-            logits = jnp.stack(kept, axis=1)            # (B, gen, V)
+            logits = jnp.concatenate(kept, axis=1)     # (B, gen, V)
         if control:
             low = reference.served_logits(config, weights, prompts, served[b],
                                           axo=traffic["axo"], precision=control)
@@ -271,7 +239,5 @@ def run(cell, run, reference, control: str | None = None) -> dict:
                   "prompt_len": int(traffic["prompt_len"]),
                   "gen": int(traffic["gen"]),
                   "axo": traffic["axo"],
-                  "model": {"d_model": cfg.d_model, "n_heads": cfg.n_heads,
-                            "kv_heads": cfg.kv_heads, "d_ff": cfg.d_ff,
-                            "vocab": cfg.vocab, "n_layers": cfg.n_layers}},
+                  "model": fam.shapes(config)},
     }

@@ -6,8 +6,10 @@ Everything a cell needs is found by name: the cell in ``BENCHMARK.json``, its
 configuration file, its traffic file ``bench/traffic/<traffic>.json`` (whose
 ``kind`` names the driver ``bench/drivers/<kind>.py``), the plain reference
 ``bench/reference/<config>.py`` and, with ``--trace 1``, one reader
-``bench/metrics/<metric>.py`` per per-layer metric.  Adding a cell or a metric
-adds files and entries; nothing here changes.
+``bench/metrics/<metric>.py`` per per-layer metric.  A serving driver builds
+the model through its family ``bench/models/<model_type>.py``.  Adding a
+configuration, a model family, a cell or a metric adds files and entries;
+nothing here changes.
 
 A run sets up (weights or dataset from the seed, every shape of the cell's
 traffic warmed), measures for ``--seconds``, then checks what the timed path
@@ -73,13 +75,14 @@ class Cell:
         return BENCH / "drivers" / f"{self.traffic['kind']}.py"
 
 
-def resolve_cell(name: str, bench_file: Path = ROOT / "BENCHMARK.json") -> Cell:
-    """Resolve a cell's configuration, traffic, reference and metric files."""
-    bench = load_json(bench_file)
+def resolve_cell(name: str, bench: dict | Path = ROOT / "BENCHMARK.json") -> Cell:
+    """Resolve a cell's configuration, traffic, reference and metric files
+    from ``BENCHMARK.json`` (or a document in its format)."""
+    if not isinstance(bench, dict):
+        bench = load_json(bench)
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
-        raise KeyError(f"no workload {name!r} in {bench_file.name}; "
-                       f"have {sorted(cells)}")
+        raise KeyError(f"no workload {name!r}; have {sorted(cells)}")
     w = cells[name]
     cfg = {c["name"]: c for c in bench["configs"]}[w["config"]]
     e2e = [m for m in bench["end_to_end"]

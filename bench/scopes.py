@@ -1,7 +1,10 @@
 """Device time of the serving program by layer, from the named scopes in a
 profiler trace (``.xplane.pb``).
 
-The program names its layers with ``jax.named_scope`` (``SCOPES``).  XLA
+The program names its layers with ``jax.named_scope``.  The names this
+file attributes time to (``SCOPES``) are the files ``scope_names/<scope>.txt``
+beside it, each a line on where the program opens that scope, so a scope
+that a new mechanism adds is one new file.  XLA
 keeps each operation's name stack in its metadata, and the profiler writes
 it, with the program's name, as the ``tf_op`` stat of the operation's event
 metadata on a device plane: ``jit(decode_step)/while/body/closed_call/
@@ -39,8 +42,8 @@ from pathlib import Path
 
 from trace_reduce import CONTAINERS, MODULES_LINE, OPS_LINE
 
-SCOPES = ("axo.quantize", "axo.gather", "axo.matmul", "attn.proj",
-          "attn.kv_update", "attn.core", "mlp", "head", "layers")
+SCOPES = tuple(sorted(p.name.removesuffix(".txt") for p in
+                     Path(__file__).with_name("scope_names").glob("*.txt")))
 OTHER = "other"
 
 

@@ -26,9 +26,10 @@ def tiny_cell():
     DSE cells carry the limits of the real cell they stand for; the tiny
     serving model has limits of its own (``serve_tiny.json``), since its
     logits lie closer together than the real model's."""
+    import design_checks
     import run
 
-    bench = _json(DATA / "design.json")
+    design = design_checks.document(BENCH.parent)
 
     def make(kind: str, trace: bool = False, **over):
         real, config, reference = {
@@ -47,11 +48,11 @@ def tiny_cell():
             # the tiny operator's tightest constraints leave empty fronts
             traffic["const_sf_grid"] = [1.0, 1.5]
         traffic.update(over)
-        cell = {w["traffic"]: w["name"] for w in bench["workloads"]}[real]
-        e2e = [m for m in bench["end_to_end"] if cell in m.get("workloads", [cell])]
-        layer = [m for m in bench["per_layer"] if cell in m["workloads"]]
-        return run.Cell(name=cell, chips=1, config=_json(DATA / config),
-                        traffic=traffic, end_to_end=e2e, per_layer=layer,
+        name = {w["traffic"]: w["name"] for w in design["workloads"]}[real]
+        cell = run.resolve_cell(name, design)
+        return run.Cell(name=name, chips=1, config=_json(DATA / config),
+                        traffic=traffic, end_to_end=cell.end_to_end,
+                        per_layer=cell.per_layer,
                         reference=BENCH / "reference" / reference)
 
     return make

@@ -1,15 +1,16 @@
 """Operation and byte counts against hand counts at the cells' shapes, and
-the peak table."""
+the peak table.  The projection shapes come from the model family
+(``bench/models/granite.py``), from the configuration file alone."""
 
 import json
-from pathlib import Path
 
 import pytest
 
 import counts
+import run
 
-GRANITE = {"d_model": 2048, "n_heads": 32, "kv_heads": 8, "d_ff": 8192,
-           "vocab": 49155, "n_layers": 40}
+CONFIG = json.loads((run.BENCH / "configs" / "granite-3-2b.json").read_text())
+GRANITE = run.load_module(run.BENCH / "models" / "granite.py").shapes(CONFIG)
 
 
 def test_unknown_device_kind_raises():
@@ -50,7 +51,10 @@ def test_granite_model_flops_per_token():
     # per layer: wq 4194304 + wk 1048576 + wv 1048576 + wo 4194304
     #            + 3 x 16777216 (gate, up, down) = 60817408
     # 40 layers + tied head 2048 x 49155 = 2533365760 matmul params
-    assert sum(k * n for k, n in counts.dense_shapes(GRANITE).values()) == 60_817_408
+    assert len(GRANITE["layers"]) == GRANITE["attn_layers"] == 40
+    for layer in GRANITE["layers"]:
+        assert sum(k * n for group in layer.values() for k, n in group.values()) \
+            == 60_817_408
     assert counts.model_flops(GRANITE, 1, 0) == 2 * 2_533_365_760
     # attention: 2 matmuls x 2 flops x 40 layers x 2048 width per context slot
     assert counts.model_flops(GRANITE, 1, 100) - counts.model_flops(GRANITE, 1, 0) \
@@ -71,6 +75,21 @@ def test_axo_calls_of_one_batch():
     assert calls[0] == (4096, 2048, 2048) and calls[1] == (4096, 2048, 512)
     assert calls[-1] == (8, 2048, 2048)
     assert counts.axo_calls(GRANITE, (), 8, 512, 64) == []
+
+
+@pytest.mark.parametrize("traffic", ["axo_decode", "exact_decode"])
+def test_counts_of_the_cells_batches_are_the_parents(traffic):
+    """What the counts gave before the family held the shapes (PR 14's
+    ``counts.py`` over the dense dimensions), to the last digit: the AxO
+    calls' order too, which fixes the roofline's sum."""
+    t = json.loads((run.BENCH / "traffic" / f"{traffic}.json").read_text())
+    b, p, g = t["batch"], t["prompt_len"], t["gen"]
+    assert counts.serve_flops(GRANITE, b, p, g) == 6_428_295_168_000
+    calls = counts.axo_calls(GRANITE, ("attn",), b, p, g)
+    # each step: every layer's wq, wk, wv, wo
+    assert calls == [(m, 2048, n) for m in [256] + [8] * 127
+                     for _ in range(40) for n in (2048, 512, 512, 2048)]
+    assert len(calls) == 20_480
 
 
 def test_idle_between_counts_uncovered_time():

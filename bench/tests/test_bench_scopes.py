@@ -164,3 +164,87 @@ def test_decode_step_hlo_names_every_scope(tiny_cell, no_persistent_cache, kind)
         want = {s for s in want if not s.startswith("axo.")}
     assert want <= named
     assert named & set(scopes.SCOPES) == want
+
+
+def test_every_named_scope_of_the_program_is_read():
+    """Each literal ``jax.named_scope`` name in the program is one of the
+    scopes this reader attributes time to (``scope_names/``)."""
+    src = Path(__file__).resolve().parents[2] / "src" / "repro"
+    named = {m for p in src.rglob("*.py")
+             for m in re.findall(r'named_scope\(\s*"([^"]+)"', p.read_text())}
+    assert named and named <= set(scopes.SCOPES), named - set(scopes.SCOPES)
+
+
+# Each listed reader of the serving cells on the committed traces, as PR 14's
+# harness read them (the four scope readers from its ``scopes.py``): the
+# cell's full-size counts over each trace's window, so the shares are
+# readings to hold fixed, not shares of this small trace's work.
+BEFORE = {
+    ("scoped", "axo_decode"): {
+        "decode_step_ms": 1.9690530000000053,
+        "axo_matmul_roofline": 20748.62861999174,
+        "serve_mfu": 504.7241303324168,
+        "device_idle_share.serve": 54.255167434274846,
+        "host_gap_ms_per_step": 2.181259,
+        "axo_glue_ms_per_step": 1.0718017979999999,
+        "mlp_ms_per_step": 0.18560749999999998,
+        "kv_update_ms_per_step": 0.0011624219999999998},
+    ("scoped", "exact_decode"): {
+        "decode_step_ms": 1.9690530000000053,
+        "axo_matmul_roofline": None,
+        "serve_mfu": 504.7241303324168,
+        "device_idle_share.serve": 54.255167434274846,
+        "host_gap_ms_per_step": 2.181259,
+        "axo_glue_ms_per_step": None,
+        "mlp_ms_per_step": 0.18560749999999998,
+        "kv_update_ms_per_step": 0.0011624219999999998},
+    ("probe", "axo_decode"): {
+        "decode_step_ms": 0.03672700000000029,
+        "axo_matmul_roofline": 6157479.59247194,
+        "serve_mfu": 574.2140605731098,
+        "device_idle_share.serve": 99.05025125760784,
+        "host_gap_ms_per_step": 3.4024615,
+        "axo_glue_ms_per_step": None,
+        "mlp_ms_per_step": None,
+        "kv_update_ms_per_step": None},
+    ("probe", "exact_decode"): {
+        "decode_step_ms": 0.03672700000000029,
+        "axo_matmul_roofline": None,
+        "serve_mfu": 574.2140605731098,
+        "device_idle_share.serve": 99.05025125760784,
+        "host_gap_ms_per_step": 3.4024615,
+        "axo_glue_ms_per_step": None,
+        "mlp_ms_per_step": None,
+        "kv_update_ms_per_step": None},
+}
+
+
+@pytest.mark.parametrize("trace,traffic", sorted(BEFORE))
+def test_listed_readers_read_the_committed_traces_as_before(trace, traffic):
+    import json
+
+    import counts
+    import run
+    import trace_reduce
+
+    path = DATA / f"{trace}.xplane.pb"
+    summary = trace_reduce.reduce_trace(path)
+    config = json.loads((run.BENCH / "configs" / "granite-3-2b.json").read_text())
+    t = json.loads((run.BENCH / "traffic" / f"{traffic}.json").read_text())
+    family = run.load_module(run.BENCH / "models" / "granite.py")
+    spans = [("bench.decode", s / 1e9, e / 1e9)
+             for name, s, e in summary.module_events if "decode_step" in name]
+    ctx = {"run": SimpleNamespace(trace_path=path, window_s=summary.window_s,
+                                  t_window=0.0, spans=spans),
+           "layer": {"batches": 2, "batch": t["batch"], "prompt_len": t["prompt_len"],
+                     "gen": t["gen"], "axo": t["axo"], "model": family.shapes(config)},
+           "trace": summary, "peaks": counts.peaks_for("TPU v5 lite"),
+           "config": config, "traffic": t}
+    listed = {m["name"] for m in run.load_json(run.ROOT / "BENCHMARK.json")["per_layer"]
+              if "granite.exact.decode" in m["workloads"]
+              or "granite.axo.decode" in m["workloads"]}
+    # axo_deploy_s reads the process's own deploy span, not a trace
+    assert listed - {"axo_deploy_s"} == set(BEFORE[trace, traffic])
+    got = {name: run.load_module(run.BENCH / "metrics" / f"{name}.py").read(ctx)
+           for name in BEFORE[trace, traffic]}
+    assert got == BEFORE[trace, traffic]

@@ -13,11 +13,12 @@ serving path:
      and dequantization.
   3. ``deploy_axo``: walk a model's param tree and build an
      :class:`AxODeployment` -- per-layer **cached** weight codes/scales and
-     pre-computed ``G_r(W)`` factors for every attention q/k/v/o, MLP and MoE
-     expert projection (plus the LM head), so decode steps never requantize or
-     look weights up again per token.  The deployment is a pytree and threads
-     through ``models.model.forward(axo=...)`` and the ``launch.steps`` steps
-     as a jit argument.
+     pre-computed ``G_r(W)`` factors for every attention q/k/v/o, Mamba
+     in/out, MLP and MoE expert projection (plus the LM head), so decode
+     steps never requantize or look weights up again per token.  The
+     deployment is a pytree and threads through
+     ``models.model.forward(axo=...)`` and the ``launch.steps`` steps as a
+     jit argument.
 
 Both the deployment and its decode steps turn codes into operand values and
 factors with :func:`code_lookup`: arithmetic decoding and a select over the
@@ -31,6 +32,7 @@ production uses the rank-R MXU path (DESIGN.md §3.2).
 from __future__ import annotations
 
 import functools
+from collections import Counter
 from dataclasses import dataclass, field
 
 import jax
@@ -225,7 +227,11 @@ def axo_linear(
 # ---------------------------------------------------------------------------
 
 #: parts of the network ``deploy_axo`` can swap onto the approximate operator
-AXO_LAYERS = ("attn", "mlp", "moe", "head")
+AXO_LAYERS = ("attn", "ssm", "mlp", "moe", "head")
+
+#: the Pallas kernel's lane width: a ``"pallas"`` entry's K and N are padded
+#: to it at deploy time, so no call pads a weight-sized table
+LANE = 128
 
 
 @functools.partial(
@@ -241,7 +247,10 @@ class AxODeployment:
     signed value matrix ``bv = signed_vals[Wq]`` (K, N), its right factors
     ``gb = G_r(Wq)`` (R, K, N) and the weight scale -- decode steps only
     quantize the (tiny) activation and look up its values and left factors
-    (:func:`code_lookup`).
+    (:func:`code_lookup`).  A ``"pallas"`` entry whose K or N is not a
+    multiple of :data:`LANE` holds ``bv``/``gb`` zero-padded to it (exact:
+    zero values and factors add nothing) and a shape-only leaf ``cols``
+    (0, N) that keeps the output's width.
     Entries for stacked layers carry a leading ``repeats`` axis so they ride
     through ``jax.lax.scan`` next to the params.
 
@@ -270,7 +279,8 @@ class AxODeployment:
         lead = x.shape[:-1]
         k = x.shape[-1]
         bv = entry["bv"]
-        n = bv.shape[-1]
+        kp, np_ = bv.shape[-2:]
+        n = entry["cols"].shape[-1] if "cols" in entry else np_
         with jax.named_scope("axo.quantize"):
             xq, sx = quantize_tensor(
                 x.reshape(-1, k).astype(jnp.float32), self.op.n_bits
@@ -278,19 +288,24 @@ class AxODeployment:
         with jax.named_scope("axo.gather"):
             # (M, K) values, (R, M, K) factors
             av, fa = code_lookup(self.op, xq, "f")
+            if kp != k:   # the activation side of a padded entry
+                av = jnp.pad(av, ((0, 0), (0, kp - k)))
+                fa = jnp.pad(fa, ((0, 0), (0, 0), (0, kp - k)))
         tel = obs.of(self.ctx)
         tel.count(f"dispatch.axo_apply.{self.impl}")
         tel.count(f"dispatch.axo_lookup.{lookup_path(self.op)}")
         with jax.named_scope("axo.matmul"):
             if self.impl == "pallas":
                 tiles = tiles_for(self.ctx, "axo_matmul.pallas",
-                                  m=av.shape[0], k=k, n=n, rank=self.op.rank)
+                                  m=av.shape[0], k=kp, n=np_, rank=self.op.rank)
                 y = axo_matmul_pallas(
                     av, bv, fa, entry["gb"],
                     interpret=not ops.on_tpu(), **tiles,
                 )
             else:
                 y = av @ bv + jnp.einsum("rmk,rkn->mn", fa, entry["gb"])
+            if np_ != n:
+                y = y[:, :n]
             y = y * (sx * entry["scale"])
             return y.reshape(*lead, n).astype(x.dtype)
 
@@ -312,20 +327,23 @@ def deploy_axo(
     * ``"attn"``  -- attention wq/wk/wv/wo (dense, no-cache, cross- and
       self-halves of attn_x, gated xattn) and MLA wq_a/wq_b/wkv_a/wo.  MLA's
       ``wkv_b`` stays exact: the absorbed form contracts its two halves
-      per-head against latents, not as a plain last-dim linear.  Mamba mixers
-      are out of scope (conv/SSM, no K->N linear on the hot path).
+      per-head against latents, not as a plain last-dim linear.
+    * ``"ssm"``   -- Mamba mixers' in_proj and out_proj, nearly all of a
+      mixer's weights; the depthwise conv, A, dt, D and the gated norm stay
+      exact, as the router does.
     * ``"mlp"``   -- dense FFN w_gate/w_up/w_down, plus MoE *shared* experts.
     * ``"moe"``   -- routed expert banks (per-expert entries; the router stays
       exact -- approximating the argmax selector changes *which* experts run,
       which is a routing decision, not arithmetic).
     * ``"head"``  -- the unembedding (tied: embed.T).
 
-    ``impl="pallas"`` runs the padded registry-tiled kernel; ``"xla"`` runs
-    the jnp reference contraction (identical math, faster under CPU jit).
+    ``impl="pallas"`` runs the registry-tiled kernel on entries padded to
+    its lane width; ``"xla"`` runs the jnp reference contraction (identical
+    math, faster under CPU jit).
 
     The build, until its arrays are on the device, is the ``axo.deploy``
-    span of ``ctx``'s telemetry, with the entry count and the deployment's
-    bytes as attributes.
+    span of ``ctx``'s telemetry, with the entry count, the count of each
+    group (``group_entries``) and the deployment's bytes as attributes.
     """
     unknown = set(layers) - set(AXO_LAYERS)
     if unknown:
@@ -335,10 +353,11 @@ def deploy_axo(
         raise ValueError(f"impl must be 'pallas' or 'xla', got {impl!r}")
     tel = obs.of(ctx)
     with tel.span("axo.deploy", impl=impl, layers=tuple(layers)) as span:
-        dep = jax.block_until_ready(
-            _build_deployment(params, op, cfg, tuple(layers), impl, ctx))
+        dep, groups = _build_deployment(
+            params, op, cfg, tuple(layers), impl, ctx)
+        dep = jax.block_until_ready(dep)
     if tel.enabled:   # the disabled sink's span is shared
-        span.attrs.update(entries=dep.n_entries,
+        span.attrs.update(entries=dep.n_entries, group_entries=groups,
                           bytes=sum(x.nbytes for x in jax.tree.leaves(dep)))
     return dep
 
@@ -348,48 +367,56 @@ def deploy_axo(
 _code_lookup_jit = jax.jit(code_lookup, static_argnums=(0, 2))
 
 
-def _build_deployment(params, op, cfg, layers, impl, ctx) -> AxODeployment:
-    count = [0]
+def _build_deployment(params, op, cfg, layers, impl, ctx):
+    """The deployment and its entry count by group."""
+    counts = Counter()
 
-    def prep(w2d):
+    def prep(w2d, group):
         """(K, N) weight -> cached codes/values/factors entry."""
         wq, sw = quantize_tensor(jnp.asarray(w2d, jnp.float32), op.n_bits)
-        count[0] += 1
+        counts[group] += 1
         bv, gb = _code_lookup_jit(op, wq, "g")   # (K, N), (R, K, N)
-        return {"bv": bv, "gb": gb, "scale": sw}
+        ent = {"bv": bv, "gb": gb, "scale": sw}
+        k, n = w2d.shape
+        pk, pn = -k % LANE, -n % LANE
+        if impl == "pallas" and (pk or pn):
+            ent["bv"] = jnp.pad(bv, ((0, pk), (0, pn)))
+            ent["gb"] = jnp.pad(gb, ((0, 0), (0, pk), (0, pn)))
+            ent["cols"] = jnp.zeros((0, n), jnp.int8)
+        return ent
 
-    def prep_r(w, tail2=None):
+    def prep_r(w, group, tail2=None):
         """Stacked (repeats, ...) weight -> entry with a leading repeats axis."""
         if tail2 is not None:
             w = w.reshape(w.shape[0], *tail2)
-        return jax.vmap(prep)(w)
+        return jax.vmap(functools.partial(prep, group=group))(w)
 
     def prep_experts(w):
         """(repeats, E, K, N) expert bank -> doubly-stacked entry."""
-        return jax.vmap(jax.vmap(prep))(w)
+        return jax.vmap(jax.vmap(functools.partial(prep, group="moe")))(w)
 
     def attn_entries(mp):
         rep, d, h, hd = mp["wq"].shape
         g = mp["wk"].shape[2]
         return {
-            "wq": prep_r(mp["wq"], (d, h * hd)),
-            "wk": prep_r(mp["wk"], (d, g * hd)),
-            "wv": prep_r(mp["wv"], (d, g * hd)),
-            "wo": prep_r(mp["wo"], (h * hd, mp["wo"].shape[3])),
+            "wq": prep_r(mp["wq"], "attn", (d, h * hd)),
+            "wk": prep_r(mp["wk"], "attn", (d, g * hd)),
+            "wv": prep_r(mp["wv"], "attn", (d, g * hd)),
+            "wo": prep_r(mp["wo"], "attn", (h * hd, mp["wo"].shape[3])),
         }
 
     def mla_entries(mp):
         r_q, h, qd = mp["wq_b"].shape[1:]
         _, v_hd, d = mp["wo"].shape[1:]
         return {
-            "wq_a": prep_r(mp["wq_a"]),
-            "wq_b": prep_r(mp["wq_b"], (r_q, h * qd)),
-            "wkv_a": prep_r(mp["wkv_a"]),
-            "wo": prep_r(mp["wo"], (mp["wo"].shape[1] * v_hd, d)),
+            "wq_a": prep_r(mp["wq_a"], "attn"),
+            "wq_b": prep_r(mp["wq_b"], "attn", (r_q, h * qd)),
+            "wkv_a": prep_r(mp["wkv_a"], "attn"),
+            "wo": prep_r(mp["wo"], "attn", (mp["wo"].shape[1] * v_hd, d)),
         }
 
     def mlp_entries(mp):
-        return {k: prep_r(mp[k])
+        return {k: prep_r(mp[k], "mlp")
                 for k in ("w_gate", "w_up", "w_down") if k in mp}
 
     def layer_entries(mixer, mlp, lp):
@@ -404,6 +431,9 @@ def _build_deployment(params, op, cfg, layers, impl, ctx) -> AxODeployment:
                 }
             elif mixer == "mla":
                 ent["mixer"] = mla_entries(lp["mixer"])
+        if mixer == "mamba" and "ssm" in layers:
+            ent["mixer"] = {k: prep_r(lp["mixer"][k], "ssm")
+                            for k in ("in_proj", "out_proj")}
         if mlp == "dense" and "mlp" in layers:
             ent["mlp"] = mlp_entries(lp["mlp"])
         elif mlp == "moe":
@@ -439,10 +469,11 @@ def _build_deployment(params, op, cfg, layers, impl, ctx) -> AxODeployment:
     if "head" in layers:
         w = (params["embed"]["tok"].T if cfg.tie_embeddings
              else params["embed"]["unembed"])
-        head = prep(w)
+        head = prep(w, "head")
 
-    return AxODeployment(
+    dep = AxODeployment(
         op=op, impl=impl, layers=layers,
         stages=stages, encoder=encoder, head=head,
-        ctx=ctx, n_entries=count[0],
+        ctx=ctx, n_entries=sum(counts.values()),
     )
+    return dep, dict(counts)

@@ -56,6 +56,7 @@ class SSMConfig:
     head_dim: int = 64
     n_groups: int = 1
     chunk: int = 128
+    norm_eps: float | None = None  # the gated norm's; None: the model's norm_eps
 
 
 @dataclass(frozen=True)

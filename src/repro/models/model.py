@@ -294,10 +294,12 @@ def _apply_layer(
     elif mixer == "mamba":
         if ctx["mode"] == "decode":
             out, (conv, state) = mamba_decode(
-                p["mixer"], h, cfg, rules, cache["conv"], cache["state"])
+                p["mixer"], h, cfg, rules, cache["conv"], cache["state"],
+                axo=ax("mixer"))
             new_cache = {"conv": conv, "state": state}
         else:
-            out, (conv, state) = mamba_apply(p["mixer"], h, cfg, rules)
+            out, (conv, state) = mamba_apply(p["mixer"], h, cfg, rules,
+                                             axo=ax("mixer"))
             if cache is not None:
                 new_cache = {"conv": conv.astype(cache["conv"].dtype), "state": state}
     else:

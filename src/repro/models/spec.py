@@ -43,7 +43,8 @@ class ParamSpec:
 def stacked(spec: ParamSpec, n: int) -> ParamSpec:
     """Add a leading scan-stack axis."""
     return ParamSpec(
-        shape=(n, *spec.shape), axes=("stack", *spec.axes), init=spec.init, scale=spec.scale
+        shape=(n, *spec.shape), axes=("stack", *spec.axes), init=spec.init,
+        scale=spec.scale, dtype=spec.dtype,
     )
 
 

@@ -5,6 +5,12 @@ inside fixed-size chunks + a linear recurrence across chunk states, all in plain
 einsums/scans so XLA maps it onto the MXU.  Decode is the O(1) recurrent update
 carrying (conv window, SSD state).  The Pallas ``ssd_scan`` kernel implements the
 same math for the TPU deployment path and is validated against this reference.
+
+With ``axo`` (an ``AxODeployment`` and the mixer's entries) the two
+projections, ``in_proj`` and ``out_proj``, run on the approximate operator;
+the conv, dt, A, the recurrence, D and the gated norm stay exact.  The gated
+norm reads no residual stream, so it takes ``SSMConfig.norm_eps`` where one
+is given, not the model's (possibly folded) ``norm_eps``.
 """
 
 from __future__ import annotations
@@ -23,6 +29,7 @@ __all__ = [
     "mamba_decode",
     "mamba_dims",
     "ssd_chunked",
+    "ssd_step",
 ]
 
 
@@ -37,6 +44,18 @@ def mamba_dims(cfg: ModelConfig) -> dict:
         "conv_dim": conv_dim,
         "d_in_proj": 2 * d_inner + 2 * s.n_groups * s.d_state + n_heads,
     }
+
+
+def _gated_norm_eps(cfg: ModelConfig) -> float:
+    eps = cfg.ssm.norm_eps
+    return cfg.norm_eps if eps is None else eps
+
+
+def _proj(p: dict, name: str, x: jnp.ndarray, axo) -> jnp.ndarray:
+    """x @ p[name], on the approximate operator where ``axo`` has an entry."""
+    if axo is not None and name in axo[1]:
+        return axo[0].apply(x, axo[1][name])
+    return x @ p[name]
 
 
 def mamba_spec(cfg: ModelConfig) -> dict:
@@ -165,13 +184,14 @@ def mamba_apply(
     cfg: ModelConfig,
     rules: ShardingRules,
     init_state: jnp.ndarray | None = None,
+    axo=None,                        # (AxODeployment, mixer entries)
 ) -> tuple[jnp.ndarray, tuple[jnp.ndarray, jnp.ndarray]]:
     """Full-sequence forward.  Returns (out, (conv_tail, ssd_state)) for cache."""
     s = cfg.ssm
     dims = mamba_dims(cfg)
     di, h, cd = dims["d_inner"], dims["n_heads"], dims["conv_dim"]
 
-    zxbcdt = xin @ p["in_proj"]
+    zxbcdt = _proj(p, "in_proj", xin, axo)
     z = zxbcdt[..., :di]
     xbc = zxbcdt[..., di : di + cd]
     dt_raw = zxbcdt[..., di + cd :]                            # (B,S,H)
@@ -195,13 +215,28 @@ def mamba_apply(
     )
     y = y + xs * p["d_skip"].astype(xs.dtype)[None, None, :, None]
     y = y.reshape(*y.shape[:2], di)
-    y = rmsnorm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
-    out = y @ p["out_proj"]
-    conv_tail = xbc_tail = None
+    y = rmsnorm(y * jax.nn.silu(z), p["norm"], _gated_norm_eps(cfg))
+    out = _proj(p, "out_proj", y, axo)
     # cache: last (d_conv - 1) *pre-activation* conv inputs
     zxbc_raw = zxbcdt[..., di : di + cd]
     conv_tail = zxbc_raw[:, -(s.d_conv - 1) :, :]
     return constrain(out, rules, "batch", "seq", "embed"), (conv_tail, state)
+
+
+def ssd_step(
+    state: jnp.ndarray,  # (B, H, P, N) float32
+    x: jnp.ndarray,      # (B, H, P)
+    dt: jnp.ndarray,     # (B, H) positive step sizes
+    a: jnp.ndarray,      # (H,) negative decay rates
+    bmat: jnp.ndarray,   # (B, H, N)
+    cmat: jnp.ndarray,   # (B, H, N)
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """One position of the recurrence: returns (y (B,H,P) float32, new state)."""
+    decay = jnp.exp(dt * a)                                    # (B,H)
+    new_state = state * decay[:, :, None, None] + jnp.einsum(
+        "bhn,bh,bhp->bhpn", bmat, dt, x.astype(jnp.float32)
+    )
+    return jnp.einsum("bhn,bhpn->bhp", cmat, new_state), new_state
 
 
 def mamba_decode(
@@ -211,13 +246,14 @@ def mamba_decode(
     rules: ShardingRules,
     conv_state: jnp.ndarray,         # (B, d_conv-1, conv_dim)
     ssd_state: jnp.ndarray,          # (B, H, P, N)
+    axo=None,                        # (AxODeployment, mixer entries)
 ) -> tuple[jnp.ndarray, tuple[jnp.ndarray, jnp.ndarray]]:
     """O(1) single-token step."""
     s = cfg.ssm
     dims = mamba_dims(cfg)
     di, h, cd = dims["d_inner"], dims["n_heads"], dims["conv_dim"]
 
-    zxbcdt = xin[:, 0] @ p["in_proj"]                          # (B, d_in_proj)
+    zxbcdt = _proj(p, "in_proj", xin[:, 0], axo)               # (B, d_in_proj)
     z = zxbcdt[..., :di]
     xbc_new = zxbcdt[..., di : di + cd]
     dt_raw = zxbcdt[..., di + cd :]
@@ -235,14 +271,9 @@ def mamba_decode(
 
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
     a = -jnp.exp(p["a_log"].astype(jnp.float32))
-    decay = jnp.exp(dt * a)                                    # (B,H)
-
-    new_state = ssd_state * decay[:, :, None, None] + jnp.einsum(
-        "bhn,bh,bhp->bhpn", bh, dt, xs.astype(jnp.float32)
-    )
-    y = jnp.einsum("bhn,bhpn->bhp", ch, new_state)
+    y, new_state = ssd_step(ssd_state, xs, dt, a, bh, ch)
     y = y + xs.astype(jnp.float32) * p["d_skip"].astype(jnp.float32)[None, :, None]
     y = y.reshape(-1, di).astype(xin.dtype)
-    y = rmsnorm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
-    out = (y @ p["out_proj"])[:, None]                          # (B,1,d)
+    y = rmsnorm(y * jax.nn.silu(z), p["norm"], _gated_norm_eps(cfg))
+    out = _proj(p, "out_proj", y, axo)[:, None]                 # (B,1,d)
     return constrain(out, rules, "batch", "seq", "embed"), (window[:, 1:], new_state)

@@ -91,8 +91,11 @@ def test_deployment_decode_shape_dispatches_pallas(monkeypatch):
     dep = deploy_axo(params, _mild_op(rank=2), cfg,
                      layers=("head",), impl="pallas")
     x = jnp.asarray(RNG.standard_normal((4, cfg.d_model)), jnp.float32)
-    dep.apply(x, dep.head)
-    assert calls == [(4, cfg.d_model)]
+    out = dep.apply(x, dep.head)
+    # the entry is stored padded to the kernel's lanes (d_model 64 -> K 128),
+    # and the activation meets it there; the output keeps the vocabulary
+    assert calls == [(4, 128)] and dep.head["bv"].shape[0] == 128
+    assert out.shape == (4, cfg.vocab)
 
 
 def _fallback_op(rank=2):
